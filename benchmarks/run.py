@@ -46,11 +46,14 @@ from __future__ import annotations
 
 import argparse
 import json
+import pathlib
 import platform
 import sys
 import time
 
 import jax
+
+from repro.launch.compile_cache import enable_compile_cache
 
 from . import (bench_apsp, bench_batching, bench_centrality,
                bench_complexity, bench_dynamic, bench_memory, bench_resume,
@@ -80,6 +83,7 @@ def main() -> None:
                          "committed baseline aggregate and exit non-zero "
                          "on hard regressions (see benchmarks/regression.py)")
     args = ap.parse_args()
+    enable_compile_cache(pathlib.Path(__file__).resolve().parents[1])
 
     rows = ["name,us_per_call,derived"]
     t0 = time.time()

@@ -13,7 +13,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs import get_arch, shapes_for
@@ -251,7 +250,7 @@ def _gnn_cell(arch_id, cfg, shape: GNNShape, mesh,
                         {k: P(all_ax, *([None] * (v.ndim - 1)))
                          if v.shape and v.shape[0] in (n_pad, e_pad)
                          else P() for k, v in batch.items()})
-            return compat.shard_map(local, mesh=mesh, in_specs=in_specs,
+            return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                                  out_specs=P())(params, mb)
     else:
         loss_fn = lambda p, mb: loss(p, mb, cfg, n_graphs)

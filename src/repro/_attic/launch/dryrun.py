@@ -21,7 +21,6 @@ import traceback     # noqa: E402
 
 import jax           # noqa: E402
 
-from repro import compat  # noqa: E402
 
 from ..configs import all_cells, shapes_for          # noqa: E402
 from .cells import build_cell, jit_cell              # noqa: E402
@@ -115,7 +114,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, out_dir: str):
     mesh = make_production_mesh(multi_pod=multi_pod)
     mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         cell = build_cell(arch, shape, mesh)
         jitted = jit_cell(cell, mesh)
         lowered = jitted.lower(*cell.args)

@@ -16,7 +16,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as P
 
 Params = Dict[str, Any]
@@ -78,7 +77,7 @@ def embed_lookup(table: jax.Array, tokens: jax.Array, dtype) -> jax.Array:
     def local(tbl, tok):
         return tbl[tok]
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         local,
         in_specs=(P(None, "model"), P(dp, *([None] * (tokens.ndim - 1)))),
         out_specs=P(dp, *([None] * (tokens.ndim - 1)), "model"),

@@ -125,8 +125,11 @@ class DawnGraph:
             self._sharded_mesh = mesh
             self._sharded_epoch = self.epoch
         if semiring not in self._sharded:
+            # "sparse" names the same form in every engine; any other
+            # mode runs the sharded executor's dense form
+            mode = "sparse" if self.options.mode == "sparse" else "dense"
             cfg = self.options.to(
-                ShardedConfig, lenient=True, semiring=semiring, mode="dense")
+                ShardedConfig, lenient=True, semiring=semiring, mode=mode)
             g = self.graph.view() if self.mutable else self.graph
             self._sharded[semiring] = prepare_sharded(
                 g, mesh, weights=self._lane_weights()

@@ -12,8 +12,9 @@ therefore different ``direction_counts``.
 :func:`build_plan` replaces both with a static roofline model:
 
   * a :class:`BackendProfile` supplies peak FLOP/s, HBM bandwidth and the
-    per-core VMEM budget (a table keyed on ``jax.default_backend()``,
-    seeded from ``launch/mesh.py``'s TPU v5e constants);
+    per-core VMEM budget (``TPU_PROFILES``, keyed on the TPU's
+    ``device_kind`` and seeded from ``launch/mesh.py``'s v5e constants,
+    plus a CPU row for the interpreter; any other device is an error);
   * per-(semiring, form) *unit costs* — seconds per modelled work unit —
     come from either the jitted sweep HLO (``launch/hlo_analysis.analyze``
     counts exact FLOPs/bytes, ``launch/roofline.roofline_terms`` converts
@@ -99,18 +100,19 @@ class BackendProfile:
     vmem_budget: int
 
 
-# Static table keyed on jax.default_backend().  The TPU row is the
-# launch/mesh.py v5e roofline; cpu/gpu rows are order-of-magnitude
-# placeholders — they only need to *rank* forms sanely, and the VMEM
-# budget still bounds interpret-mode tile choices.
-STATIC_PROFILES: Dict[str, BackendProfile] = {
-    "tpu": BackendProfile("tpu", PEAK_FLOPS_BF16, HBM_BW,
-                          kernel_common.VMEM_BUDGET_BYTES),
-    "gpu": BackendProfile("gpu", 1.0e14, 1.0e12,
-                          kernel_common.VMEM_BUDGET_BYTES),
-    "cpu": BackendProfile("cpu", 2.0e11, 5.0e10,
-                          kernel_common.VMEM_BUDGET_BYTES),
+# Roofline constants per TPU ``device_kind`` (what
+# ``jax.devices()[0].device_kind`` reports).  v5e: Google Cloud "TPU v5e"
+# documentation, the launch/mesh.py constants — 197 TFLOP/s bf16,
+# 819 GB/s HBM.  A TPU kind missing here is an error, not a default.
+TPU_PROFILES: Dict[str, BackendProfile] = {
+    "TPU v5 lite": BackendProfile("TPU v5 lite", PEAK_FLOPS_BF16, HBM_BW,
+                                  kernel_common.VMEM_BUDGET_BYTES),
 }
+# The CPU backend runs the tests and the Pallas interpreter: its row only
+# has to rank forms sanely, and its VMEM budget bounds interpret-mode
+# tile choices.  It never prices a device.
+CPU_PROFILE = BackendProfile("cpu", 2.0e11, 5.0e10,
+                             kernel_common.VMEM_BUDGET_BYTES)
 
 
 def device_fingerprint() -> str:
@@ -122,10 +124,19 @@ def device_fingerprint() -> str:
 
 
 def backend_profile(fingerprint: Optional[str] = None) -> BackendProfile:
-    """Profile for ``fingerprint`` (default: the current device), from
-    the static table keyed on its backend prefix."""
+    """Profile for ``fingerprint`` (default: the current device): the
+    ``TPU_PROFILES`` row of its device kind on a TPU, the CPU row on the
+    CPU backend.  Any other device raises ``ValueError``."""
     fp = fingerprint or device_fingerprint()
-    base = STATIC_PROFILES.get(fp.split(":", 1)[0], STATIC_PROFILES["cpu"])
+    platform, _, kind = fp.partition(":")
+    if platform == "tpu" and kind in TPU_PROFILES:
+        base = TPU_PROFILES[kind]
+    elif platform == "cpu":
+        base = CPU_PROFILE
+    else:
+        raise ValueError(
+            f"no roofline profile for device {fp!r}; known TPU kinds: "
+            f"{sorted(TPU_PROFILES)} (add a row with its published peaks)")
     return dataclasses.replace(base, name=fp)
 
 

@@ -46,7 +46,6 @@ import numpy as np
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import compat
 from ..graph.csr import CSRGraph, _round_up
 from ..graph.partition import edge_partition_global
 from ..kernels import registry as kernel_registry
@@ -235,9 +234,13 @@ def prepare_sharded(g: CSRGraph, mesh: Mesh, *, weights=None,
                 w_l = jax.device_put(parts["w"], lane_sharding)
             m_local = parts["e_pad"]
         else:
-            src_l, dst_l = g.src, g.dst
+            # replicated on every device of the mesh, not left on the
+            # default device for each call to copy out
+            replicated = NamedSharding(mesh, P())
+            src_l = jax.device_put(g.src, replicated)
+            dst_l = jax.device_put(g.dst, replicated)
             if tropical:
-                w_l = jnp.asarray(lanes)
+                w_l = jax.device_put(lanes, replicated)
             m_local = g.m_pad
 
     deg = jnp.zeros(n_pad, jnp.float32).at[: g.n_nodes].set(
@@ -319,6 +322,7 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
                         cand = jax.lax.dot_general(
                             fs_k, dense_l.astype(jnp.float32),
                             (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
                         return jnp.where(d == UNREACHED, cand, 0.0)
 
@@ -424,10 +428,10 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
                         # so the local scatter-adds psum to the exact
                         # per-node path count
                         d, sg = ds
-                        active = f[..., src_e] != 0
-                        contrib = jnp.where(active, sg[..., src_e], 0.0)
+                        # one lane gather (see sweep.tropical_forms)
+                        fs = jnp.where(f != 0, sg, 0.0)
                         cand_p = jnp.zeros(d.shape, jnp.float32).at[
-                            ..., dst_e].add(contrib)
+                            ..., dst_e].add(fs[..., src_e])
                         new, ds2 = counting_epilogue(cand_p, d, sg, step)
                         return new, ds2, p
                 else:
@@ -513,7 +517,7 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
         if (vertex_sharded and cfg.need_sparse) else P()
     w_spec = lane_spec if tropical else P()   # boolean w_l is a 1-D dummy
 
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         run_local, mesh=mesh,
         in_specs=(dense_spec, lane_spec, lane_spec, w_spec, P(), P(),
                   row_spec, row_spec, row_spec, P()),

@@ -21,8 +21,12 @@ def packed_width(n: int) -> int:
     return (n + WORD - 1) // WORD
 
 
+@jax.jit
 def pack_bits(x: jax.Array) -> jax.Array:
-    """(..., n) bool/int -> (..., ceil(n/32)) uint32 (little-endian bits)."""
+    """(..., n) bool/int -> (..., ceil(n/32)) uint32 (little-endian bits).
+    Jitted so the widen-shift-sum fuses into one pass: called eagerly on
+    an (n_pad, n_pad) adjacency it would otherwise materialize two
+    uint32 copies of it."""
     n = x.shape[-1]
     w = packed_width(n)
     pad = w * WORD - n

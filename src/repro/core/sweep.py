@@ -280,13 +280,6 @@ def _pull_chunk_size(n_pad: int, preferred: int) -> int:
     return n_pad
 
 
-def _pull_kernel_wk(words: int) -> int:
-    for wk in (128, 64, 32, 16, 8, 4):
-        if words % wk == 0:
-            return wk
-    return words
-
-
 def boolean_forms(adj, adj_pull, src_idx, dst_idx, *, n_pad: int, s: int,
                   bn: int = 128, bk: int = 128, pull_chunk: int = 512,
                   use_kernel: bool = False, interpret: bool = True,
@@ -313,7 +306,7 @@ def boolean_forms(adj, adj_pull, src_idx, dst_idx, *, n_pad: int, s: int,
     """
     bs = min(s, 128)
     chunk = _pull_chunk_size(n_pad, pull_chunk)
-    wk = _pull_kernel_wk(max(n_pad // 32, 1))
+    wk = kernel_common.word_tile(max(n_pad // 32, 1))
 
     if use_kernel:
         K = kernel_registry.get(BOOLEAN).forms
@@ -322,7 +315,7 @@ def boolean_forms(adj, adj_pull, src_idx, dst_idx, *, n_pad: int, s: int,
         # be rectangular (a sharded K-row block packs n/C contraction rows)
         # — so its word tile comes off the operand, not n_pad.  The f32
         # GEMM push survives as the registry's "push_f32" form.
-        wk_push = _pull_kernel_wk(adj_pull.shape[1])
+        wk_push = kernel_common.word_tile(adj_pull.shape[1])
 
         def push(f, d, p, step):
             new, dist = K["push"](pack_bits(f != 0), adj_pull, d, step,
@@ -412,10 +405,11 @@ def tropical_forms(wdense, src_idx, dst_idx, w_edges, *,
     longest shortest path's hop count (Bellman-Ford depth).
     """
     def sparse_ref(f, d, p, step):
-        cand = d[..., src_idx] + w_edges
-        if use_frontier:
-            cand = jnp.where(f[..., src_idx] != 0, cand, INF)
-        nd = d.at[..., dst_idx].min(cand)
+        # mask on the vertex side, then ONE lane gather: XLA's TPU
+        # compiler miscompiles a batched scatter whose updates fuse two
+        # lane gathers (wrong minima and sums on a v5e)
+        fd = jnp.where(f != 0, d, INF) if use_frontier else d
+        nd = d.at[..., dst_idx].min(fd[..., src_idx] + w_edges)
         new = nd < d
         return new.astype(jnp.int8), nd, p
 
@@ -547,6 +541,7 @@ def counting_forms(adj, src_idx, dst_idx, *, n_pad: int = 0, s: int = 0,
             cand = jax.lax.dot_general(
                 fs, adj.astype(jnp.float32),
                 (((fs.ndim - 1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,   # exact f32 counts
                 preferred_element_type=jnp.float32)
             new = (cand > 0) & (d == UNREACHED)
             return (new.astype(jnp.int8),
@@ -558,8 +553,9 @@ def counting_forms(adj, src_idx, dst_idx, *, n_pad: int = 0, s: int = 0,
         # exact path count — the non-idempotent analogue of SOVM's
         # scatter-OR
         d, sg = d_pair
-        contrib = jnp.where(f[..., src_idx] != 0, sg[..., src_idx], 0.0)
-        cand = jnp.zeros(d.shape, jnp.float32).at[..., dst_idx].add(contrib)
+        fs = jnp.where(f != 0, sg, 0.0)     # one lane gather (see tropical)
+        cand = jnp.zeros(d.shape, jnp.float32).at[..., dst_idx].add(
+            fs[..., src_idx])
         new = (cand > 0) & (d == UNREACHED)
         return (new.astype(jnp.int8),
                 (jnp.where(new, step, d), jnp.where(new, cand, sg)), p)

@@ -28,12 +28,12 @@ def vmem_bytes(*, form: str = "push", bs: int | None = None, bn: int = 128,
                                       f_itemsize=1, a_itemsize=1,
                                       d_itemsize=4, acc_itemsize=4,
                                       out_itemsizes=(1, 4))
-    if form == "fused":  # whole (n, W) uint32 operand + resident tile state
-        b = 128 if bs is None else bs
+    if form == "fused":  # whole (n, W) uint32 operand, its transposed
+        b = 128 if bs is None else bs   # copy, and resident tile state
         words = max(n // 32, 1)
         return common.fused_vmem_bytes(
-            bs=b, n=n, operand_bytes=n * words * 4,
-            frontier_bytes=b * words * 4,
+            bs=b, n=n, operand_bytes=2 * n * words * 4,
+            frontier_bytes=b * n * (1 + 4),   # i8 rows + (bs, n) u32 words
             state_itemsizes=(4,),          # dist i32 (carried in-register)
             out_itemsizes=(1, 4))          # new i8 + dist i32 out
     assert form == "pull", form    # uint32 words + i32 dist/acc, i8+i32 out

@@ -54,8 +54,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ...core.frontier import pack_bits as frontier_pack_bits
 from .. import common
 
 
@@ -132,7 +132,7 @@ def fused_sweep(frontier: jax.Array, adj: jax.Array, dist: jax.Array,
 def _packed_pull_kernel(step_ref,                 # scalar prefetch
                         f_ref, at_ref, dist_ref,  # VMEM in
                         new_ref, dist_out_ref,    # VMEM out
-                        acc_ref):                 # VMEM scratch (bs, bn) int32
+                        acc_ref, at_t_ref):       # VMEM scratch
     k = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -140,7 +140,7 @@ def _packed_pull_kernel(step_ref,                 # scalar prefetch
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] = _word_hits(f_ref[...], at_ref[...], acc_ref[...])
+    acc_ref[...] = _word_hits(f_ref, at_ref, at_t_ref, acc_ref[...])
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -150,18 +150,21 @@ def _packed_pull_kernel(step_ref,                 # scalar prefetch
         dist_out_ref[...] = jnp.where(new, step_ref[0], dist)
 
 
-def _word_hits(f: jax.Array, at: jax.Array, acc: jax.Array) -> jax.Array:
-    """OR over packed words: acc[s, j] |= any_w(f[s, w] & at[j, w]).
-    ``f`` (bs, wk) uint32, ``at`` (bn, wk) uint32, ``acc`` (bs, bn) int32
-    — the single VPU inner loop shared by the packed pull AND packed push
-    kernels (one word of 32 contraction lanes per step)."""
-    def word(w, acc):
-        fw = jax.lax.dynamic_slice_in_dim(f, w, 1, 1)    # (bs, 1)
-        aw = jax.lax.dynamic_slice_in_dim(at, w, 1, 1)   # (bn, 1)
-        pair = fw & aw.reshape(1, -1)                    # (bs, bn) uint32
-        return acc | (pair != 0).astype(jnp.int32)
+def _or_hit(acc: jax.Array, fw: jax.Array, aw: jax.Array) -> jax.Array:
+    """One word of the (∨, ∧) product: ``fw`` (bs, 1) frontier words
+    against ``aw`` (1, bn) in-neighbour words — 32 contraction lanes."""
+    return acc | ((fw & aw) != 0).astype(jnp.int32)
 
-    return jax.lax.fori_loop(0, f.shape[1], word, acc)
+
+def _word_hits(f_ref, at_ref, at_t_ref, acc: jax.Array) -> jax.Array:
+    """OR over packed words: acc[s, j] |= any_w(f[s, w] & at[j, w]).
+    ``f_ref`` (bs, wk) uint32, ``at_ref`` (bn, wk) uint32, ``acc`` (bs,
+    bn) int32 — the VPU inner loop shared by the packed pull AND packed
+    push kernels.  The operand block is transposed once per grid step
+    into ``at_t_ref`` (wk, bn) so each word is a row (a sublane load)
+    that broadcasts against a frontier column."""
+    at_t_ref[...] = at_ref[...].T
+    return common.lane_fold(f_ref, at_t_ref, acc, _or_hit)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "bn", "wk", "interpret"))
@@ -170,7 +173,9 @@ def packed_pull_sweep(frontier_packed: jax.Array, adj_in_packed: jax.Array,
                       bn: int = 128, wk: int = 128, interpret: bool = False):
     """Bit-packed pull sweep.  frontier_packed (S, W) uint32,
     adj_in_packed (n, W) uint32 (row j = packed in-neighbours of j),
-    dist (S, n) int32.  S % bs == 0, n % bn == 0, W % wk == 0."""
+    dist (S, n) int32.  S % bs == 0, n % bn == 0, W % wk == 0, and the
+    word tile ``wk`` is a multiple of 128 or the whole W (a block's last
+    dim — ``common.word_tile`` picks it)."""
     s, w = frontier_packed.shape
     n = adj_in_packed.shape[0]
     assert adj_in_packed.shape == (n, w) and dist.shape == (s, n)
@@ -180,7 +185,9 @@ def packed_pull_sweep(frontier_packed: jax.Array, adj_in_packed: jax.Array,
 
     grid_spec = common.pull_grid_spec(gi, gj, gk, bs=bs, bn=bn, wk=wk,
                                       num_scalar_prefetch=1,
-                                      acc_dtype=jnp.int32)
+                                      acc_dtype=jnp.int32,
+                                      extra_scratch=[
+                                          pltpu.VMEM((wk, bn), jnp.uint32)])
     new, dist_out = pl.pallas_call(
         _packed_pull_kernel,
         grid_spec=grid_spec,
@@ -200,7 +207,7 @@ def packed_pull_sweep(frontier_packed: jax.Array, adj_in_packed: jax.Array,
 def _packed_push_kernel(f_occ_ref, o_occ_ref, step_ref,   # scalar prefetch
                         f_ref, at_ref, dist_ref,          # VMEM in
                         new_ref, dist_out_ref,            # VMEM out
-                        acc_ref):                         # VMEM scratch i32
+                        acc_ref, at_t_ref):               # VMEM scratch
     i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -212,7 +219,7 @@ def _packed_push_kernel(f_occ_ref, o_occ_ref, step_ref,   # scalar prefetch
 
     @pl.when(live)
     def _accumulate():
-        acc_ref[...] = _word_hits(f_ref[...], at_ref[...], acc_ref[...])
+        acc_ref[...] = _word_hits(f_ref, at_ref, at_t_ref, acc_ref[...])
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -230,9 +237,10 @@ def packed_push_sweep(frontier_packed: jax.Array, adj_in_packed: jax.Array,
     frontier over the contraction axis — adj_in_packed (n, W) uint32 (the
     same operand the pull kernel reads; for a sharded K-row block the W
     words cover the block's k rows), dist (S, n) int32.  S % bs == 0,
-    n % bn == 0, W % wk == 0.  Emits NO f32 GEMM: the (∨, ∧) product is
-    pure uint32 word AND/OR on the VPU (paper Eq. 13: 32 lanes/word),
-    gated by the push kernel's f_occ/o_occ occupancy tables."""
+    n % bn == 0, W % wk == 0 (``wk`` a multiple of 128 or the whole W).
+    Emits NO f32 GEMM: the (∨, ∧) product is pure uint32 word AND/OR on
+    the VPU (paper Eq. 13: 32 lanes/word), gated by the push kernel's
+    f_occ/o_occ occupancy tables."""
     s, w = frontier_packed.shape
     n = adj_in_packed.shape[0]
     assert adj_in_packed.shape == (n, w) and dist.shape == (s, n)
@@ -245,7 +253,9 @@ def packed_push_sweep(frontier_packed: jax.Array, adj_in_packed: jax.Array,
 
     grid_spec = common.pull_grid_spec(gi, gj, gk, bs=bs, bn=bn, wk=wk,
                                       num_scalar_prefetch=3,
-                                      acc_dtype=jnp.int32)
+                                      acc_dtype=jnp.int32,
+                                      extra_scratch=[
+                                          pltpu.VMEM((wk, bn), jnp.uint32)])
     new, dist_out = pl.pallas_call(
         _packed_push_kernel,
         grid_spec=grid_spec,
@@ -263,47 +273,58 @@ def packed_push_sweep(frontier_packed: jax.Array, adj_in_packed: jax.Array,
 # fixpoint — per invocation, Fact 1 evaluated in-kernel
 # --------------------------------------------------------------------------
 
-def _pack_words(mask: jax.Array) -> jax.Array:
-    """(bs, n) bool -> (bs, n/32) uint32 — in-kernel re-pack of the new
-    frontier between fused sweeps.  Bit-for-bit the same little-endian
-    layout as ``core.frontier.pack_bits`` (n is 128-aligned, no padding)."""
-    bs, n = mask.shape
-    bits = mask.reshape(bs, n // 32, 32).astype(jnp.uint32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(bits << shifts, axis=-1, dtype=jnp.uint32)
+def _group_words(mask: jax.Array) -> jax.Array:
+    """(bs, n) bool -> (bs, n) uint32 whose lane 32·w holds packed word
+    w — bit b = ``mask[:, 32w + b]``, the little-endian layout of
+    ``core.frontier.pack_bits``.  The in-kernel re-pack of the new
+    frontier between fused sweeps: each lane gets its bit at position
+    ``lane % 32``, then five lane rotations OR lanes l .. l+31 into lane
+    l.  Only lanes 32·w are read (``lane_fold(stride=32)``), and for
+    them the window never wraps past n, so no compaction (a lane-split
+    reshape Mosaic cannot lower) is needed."""
+    n = mask.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, mask.shape, 1)
+    v = mask.astype(jnp.uint32) << (lane & 31).astype(jnp.uint32)
+    for sh in (1, 2, 4, 8, 16):
+        v = v | pltpu.roll(v, n - sh, 1)      # v[l] |= v[l + sh]
+    return v
 
 
 def _fused_boolean_kernel(meta_ref,                        # scalar prefetch
                           f_ref, at_ref, dist_ref,         # VMEM in
                           new_ref, dist_out_ref,           # VMEM out
-                          prod_ref, stop_ref,              # VMEM out (1, 1)
+                          prod_ref, stop_ref,              # SMEM out (gi,)
+                          at_t_ref, words_ref,             # VMEM scratch
                           *, max_sweeps: int):
     step0 = meta_ref[0]
     n_run = meta_ref[1]
-    at = at_ref[...]                     # (n, W) uint32, resident throughout
+    at_t_ref[...] = at_ref[...].T        # (W, n) uint32, resident throughout
     d0 = dist_ref[...]                   # (bs, n) int32
+    words_ref[...] = _group_words(f_ref[...] != 0)
 
     def sweep(t, carry):
-        done, prod, f, d, new8 = carry
+        done, prod, d, new8 = carry
         live = (done == 0) & (t < n_run)
-        hits = _word_hits(f, at, jnp.zeros(d.shape, jnp.int32))
+        hits = common.lane_fold(words_ref, at_t_ref,
+                                jnp.zeros(d.shape, jnp.int32), _or_hit,
+                                stride=32)
         new = (hits > 0) & (d < 0)
         any_new = jnp.any(new)
         d = jnp.where(new & live, step0 + 1 + t, d)
         new8 = jnp.where(live, new.astype(jnp.int8), new8)
-        f = jnp.where(live, _pack_words(new), f)
+        words_ref[...] = jnp.where(live, _group_words(new), words_ref[...])
         prod = prod + (live & any_new).astype(jnp.int32)
         done = done | (live & ~any_new).astype(jnp.int32)
-        return done, prod, f, d, new8
+        return done, prod, d, new8
 
-    done, prod, _, d, new8 = jax.lax.fori_loop(
+    done, prod, d, new8 = jax.lax.fori_loop(
         0, max_sweeps, sweep,
-        (jnp.int32(0), jnp.int32(0), f_ref[...], d0,
-         jnp.zeros(d0.shape, jnp.int8)))
+        (jnp.int32(0), jnp.int32(0), d0, jnp.zeros(d0.shape, jnp.int8)))
     new_ref[...] = new8
     dist_out_ref[...] = d
-    prod_ref[0, 0] = prod
-    stop_ref[0, 0] = done
+    i = pl.program_id(0)
+    prod_ref[i] = prod
+    stop_ref[i] = done
 
 
 @functools.partial(jax.jit,
@@ -314,7 +335,7 @@ def fused_boolean_multisweep(frontier: jax.Array, adj_in_packed: jax.Array,
                              max_sweeps: int = 1, interpret: bool = False):
     """Run up to ``n_run`` boolean sweeps (``n_run <= max_sweeps``, the
     static unroll bound) in ONE kernel invocation.  frontier (S, n) int8
-    (packed on entry; re-packed in-VMEM between sweeps), adj_in_packed
+    (packed in VMEM before every sweep), adj_in_packed
     (n, W) uint32 fully resident, dist (S, n) int32, ``step`` the sweeps
     already executed (sweep t writes distance step + 1 + t).
 
@@ -334,20 +355,21 @@ def fused_boolean_multisweep(frontier: jax.Array, adj_in_packed: jax.Array,
     assert s % bs == 0 and n % 128 == 0, (s, n, bs)
     gi = s // bs
 
-    fp = frontier_pack_bits(frontier != 0)                # (S, W)
     meta = jnp.stack([jnp.asarray(step, jnp.int32),
                       jnp.asarray(n_run, jnp.int32)])
 
-    grid_spec = common.fused_grid_spec(gi, bs=bs, n=n, f_block=(bs, w),
-                                       op_block=(n, w))
+    grid_spec = common.fused_grid_spec(
+        gi, bs=bs, n=n, f_block=(bs, n), op_block=(n, w),
+        scratch_shapes=[pltpu.VMEM((w, n), jnp.uint32),
+                        pltpu.VMEM((bs, n), jnp.uint32)])
     new, dist_out, prod, stop = pl.pallas_call(
         functools.partial(_fused_boolean_kernel, max_sweeps=max_sweeps),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((s, n), jnp.int8),
                    jax.ShapeDtypeStruct((s, n), jnp.int32),
-                   jax.ShapeDtypeStruct((gi, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((gi, 1), jnp.int32)],
+                   jax.ShapeDtypeStruct((gi,), jnp.int32),
+                   jax.ShapeDtypeStruct((gi,), jnp.int32)],
         compiler_params=common.fused_compiler_params(),
         interpret=interpret,
-    )(meta, fp, adj_in_packed, dist)
+    )(meta, frontier, adj_in_packed, dist)
     return new, dist_out, jnp.max(prod), jnp.min(stop) > 0

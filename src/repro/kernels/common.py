@@ -14,10 +14,11 @@ instantiations of the same skeleton —
     step before any VMEM compute;
   * MXU-aligned tile sizes validated against the per-core VMEM budget.
 
-This module owns the pieces the semirings share: the jax-version
-compiler-params shim, interpret-mode backend detection, the blockwise
-``any`` reduction behind both occupancy tables, the push/pull grid-spec
-builders, and the VMEM budget math quoted in docs/ARCHITECTURE.md.
+This module owns the pieces the semirings share: interpret-mode backend
+detection, the blockwise ``any`` reduction behind both occupancy tables,
+the lane fold behind every VPU contraction, the push/pull grid-spec
+builders, the word-tile rule, and the VMEM budget math quoted in
+docs/ARCHITECTURE.md.
 """
 from __future__ import annotations
 
@@ -28,11 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names the TPU compiler-params struct TPUCompilerParams
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 MXU_ALIGN = 128                      # matmul dims must be multiples of this
+LANES = 128                          # a block's last dim: multiple, or whole
 # default per-core budget when no TuningPlan overrides it (the historical
 # hard-coded table value; core/autotune.py BackendProfile carries the
 # per-device figure and threads it through vmem_limit())
@@ -57,15 +55,30 @@ def tile_candidates(n_pad: int) -> tuple[int, ...]:
     return cands or (MXU_ALIGN,)
 
 
+# scoped-VMEM ceiling for the fused kernels, whose whole operand, its
+# transposed copy and (bs, n) state values all live for the sweep block
+# (v5e has 128 MiB of VMEM per core; the default scoped limit is 16 MiB)
+FUSED_VMEM_LIMIT_BYTES = 96 * 2 ** 20
+
+
 def default_interpret() -> bool:
     """Pallas kernels execute op-by-op (interpret mode) off-TPU."""
     return jax.default_backend() != "tpu"
 
 
+def word_tile(words: int) -> int:
+    """Word (contraction) tile of the bit-packed kernels: a block's last
+    dim must be a multiple of 128 lanes or the whole array width, so
+    take 128 words when they divide ``words`` and the whole width
+    otherwise (``n_pad`` is only 128-aligned, so ``n_pad / 32`` often is
+    not)."""
+    return LANES if words % LANES == 0 else words
+
+
 def sweep_compiler_params():
     """The shared grid semantics: (i, j) output tiles are parallel, the
     K reduction axis is sequential (scratch accumulator carries state)."""
-    return CompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
@@ -73,7 +86,51 @@ def fused_compiler_params():
     """Fused multi-sweep grids iterate source tiles only; each tile runs
     its whole sweep block to convergence, so the single axis is
     "arbitrary" (tiles are independent but internally stateful)."""
-    return CompilerParams(dimension_semantics=("arbitrary",))
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES)
+
+
+# --------------------------------------------------------------------------
+# the VPU contraction shared by the bit-packed and min-plus kernels
+# --------------------------------------------------------------------------
+
+def lane_fold(x_ref, y_ref, acc: jax.Array, step, *,
+              stride: int = 1) -> jax.Array:
+    """Fold ``step`` over the K contraction steps of ``x_ref`` (rows,
+    K·stride) — K on lanes — and ``y_ref`` (K, cols) — K on sublanes:
+
+        acc = step(acc, x[:, k·stride : k·stride+1], y[k:k+1, :])
+
+    for k = 0 .. K-1: a semiring outer-product accumulation, one (rows,
+    1) column of x against one (1, cols) row of y per step.  Mosaic has
+    no dynamic slice along lanes, so x is walked in 128-lane chunks (a
+    ``fori_loop`` of aligned dynamic loads), each chunk unrolled with
+    static lane slices; y rows are dynamic sublane loads.  A K that does
+    not fill whole chunks ends in a static tail.  ``stride`` > 1 reads
+    every stride-th lane of x (the fused boolean kernel keeps word w at
+    lane 32·w).  The fold is k-ascending, though the idempotent ⊕'s
+    (OR, min) do not need any order for bit-identity."""
+    k_total = y_ref.shape[0]
+    per_chunk = LANES // stride
+
+    def fold(acc, xc, row0, width):
+        for t in range(width):
+            acc = step(acc, xc[:, t * stride:t * stride + 1],
+                       y_ref[pl.ds(row0 + t, 1), :])
+        return acc
+
+    n_full = k_total // per_chunk
+    if n_full:
+        def chunk(c, acc):
+            base = pl.multiple_of(c * LANES, LANES)
+            return fold(acc, x_ref[:, pl.ds(base, LANES)], c * per_chunk,
+                        per_chunk)
+
+        acc = jax.lax.fori_loop(0, n_full, chunk, acc)
+    lo = n_full * per_chunk
+    if lo < k_total:
+        acc = fold(acc, x_ref[:, lo * stride:], lo, k_total - lo)
+    return acc
 
 
 # --------------------------------------------------------------------------
@@ -133,9 +190,11 @@ def push_grid_spec(gi: int, gj: int, gk: int, *, bs: int, bn: int, bk: int,
 
 
 def pull_grid_spec(gi: int, gj: int, gk: int, *, bs: int, bn: int, wk: int,
-                   num_scalar_prefetch: int, acc_dtype) -> "pltpu.PrefetchScalarGridSpec":
+                   num_scalar_prefetch: int, acc_dtype,
+                   extra_scratch=()) -> "pltpu.PrefetchScalarGridSpec":
     """Grid spec for pull-direction sweeps (bit-packed boolean): packed
-    frontier block (i, k), packed in-neighbour block (j, k)."""
+    frontier block (i, k), packed in-neighbour block (j, k), the (bs, bn)
+    accumulator plus any ``extra_scratch`` (the transposed word block)."""
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch,
         grid=(gi, gj, gk),
@@ -148,24 +207,25 @@ def pull_grid_spec(gi: int, gj: int, gk: int, *, bs: int, bn: int, wk: int,
             pl.BlockSpec((bs, bn), lambda i, j, k, *_: (i, j)),
             pl.BlockSpec((bs, bn), lambda i, j, k, *_: (i, j)),
         ],
-        scratch_shapes=[pltpu.VMEM((bs, bn), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((bs, bn), acc_dtype)] + list(extra_scratch),
     )
 
 
 def fused_grid_spec(gi: int, *, bs: int, n: int, f_block, op_block,
-                    num_scalar_prefetch: int = 1,
-                    n_state: int = 1) -> "pltpu.PrefetchScalarGridSpec":
+                    num_scalar_prefetch: int = 1, n_state: int = 1,
+                    scratch_shapes=()) -> "pltpu.PrefetchScalarGridSpec":
     """Grid spec for the fused multi-sweep (persistent) kernels: grid
     ``(gi,)`` over source tiles only — each grid step keeps its frontier
     block ``f_block`` at ``(i, 0)``, the *whole* operand ``op_block`` at
     ``(0, 0)``, and ``n_state`` per-row state tiles ``(bs, n)`` resident
     in VMEM while it runs up to ``max_sweeps`` sweeps internally (the
     Fact-1 check fires in-kernel).  Outputs: the last sweep's improved
-    mask, the updated state arrays, and two ``(1, 1)`` per-tile scalars —
-    the productive-sweep count and the converged flag — that the wrapper
-    max/all-reduces into the loop driver's accounting."""
+    mask, the updated state arrays, and two ``(gi,)`` int32 per-tile
+    scalars in SMEM — the productive-sweep count and the converged flag,
+    written at ``[program_id(0)]`` — that the wrapper max/all-reduces
+    into the loop driver's accounting."""
     state_spec = pl.BlockSpec((bs, n), lambda i, *_: (i, 0))
-    flag_spec = pl.BlockSpec((1, 1), lambda i, *_: (i, 0))
+    flag_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch,
         grid=(gi,),
@@ -174,6 +234,7 @@ def fused_grid_spec(gi: int, *, bs: int, n: int, f_block, op_block,
             pl.BlockSpec(op_block, lambda i, *_: (0, 0)),
         ] + [state_spec] * n_state,
         out_specs=[state_spec] * (n_state + 1) + [flag_spec, flag_spec],
+        scratch_shapes=list(scratch_shapes),
     )
 
 
@@ -193,8 +254,9 @@ def push_vmem_bytes(bs: int, bn: int, bk: int, *, f_itemsize: int,
 def pull_vmem_bytes(bs: int, bn: int, wk: int, *, word_itemsize: int,
                     d_itemsize: int, acc_itemsize: int,
                     out_itemsizes: Sequence[int]) -> int:
-    """Resident VMEM for one pull-style grid step."""
-    return ((bs + bn) * wk * word_itemsize
+    """Resident VMEM for one pull-style grid step: frontier and operand
+    word blocks plus the operand block's (wk, bn) transposed copy."""
+    return ((bs + 2 * bn) * wk * word_itemsize
             + bs * bn * (d_itemsize + acc_itemsize + sum(out_itemsizes)))
 
 

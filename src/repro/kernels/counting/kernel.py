@@ -43,6 +43,10 @@ from jax.experimental import pallas as pl
 
 from .. import common
 
+# path counts need all 24 bits of the f32 mantissa: the MXU's default
+# f32 precision rounds the operands to bfloat16 (8 bits) first
+EXACT = jax.lax.Precision.HIGHEST
+
 
 def _counting_sweep_kernel(f_occ_ref, o_occ_ref, step_ref,   # scalar prefetch
                            fs_ref, a_ref, dist_ref, sig_ref,  # VMEM in
@@ -61,7 +65,7 @@ def _counting_sweep_kernel(f_occ_ref, o_occ_ref, step_ref,   # scalar prefetch
     def _accumulate():
         acc_ref[...] += jnp.dot(
             fs_ref[...], a_ref[...].astype(jnp.float32),
-            preferred_element_type=jnp.float32)
+            precision=EXACT, preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -121,7 +125,7 @@ def fused_counting_sweep(fsigma: jax.Array, adj: jax.Array, dist: jax.Array,
 def _fused_counting_kernel(meta_ref,                       # scalar prefetch
                            f_ref, a_ref, dist_ref, sig_ref,  # VMEM in
                            new_ref, dist_out_ref, sig_out_ref,  # VMEM out
-                           prod_ref, stop_ref,             # VMEM out (1, 1)
+                           prod_ref, stop_ref,             # SMEM out (gi,)
                            *, max_sweeps: int):
     step0 = meta_ref[0]
     n_run = meta_ref[1]
@@ -133,7 +137,8 @@ def _fused_counting_kernel(meta_ref,                       # scalar prefetch
         done, prod, f8, d, sg, new8 = carry
         live = (done == 0) & (t < n_run)
         fs = jnp.where(f8 != 0, sg, 0.0)
-        cand = jnp.dot(fs, a, preferred_element_type=jnp.float32)
+        cand = jnp.dot(fs, a, precision=EXACT,
+                       preferred_element_type=jnp.float32)
         new = (cand > 0) & (d < 0)
         any_new = jnp.any(new)
         upd = new & live
@@ -152,8 +157,9 @@ def _fused_counting_kernel(meta_ref,                       # scalar prefetch
     new_ref[...] = new8
     dist_out_ref[...] = d
     sig_out_ref[...] = sg
-    prod_ref[0, 0] = prod
-    stop_ref[0, 0] = done
+    i = pl.program_id(0)
+    prod_ref[i] = prod
+    stop_ref[i] = done
 
 
 @functools.partial(jax.jit,
@@ -187,8 +193,8 @@ def fused_counting_multisweep(frontier: jax.Array, adj: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((s, n), jnp.int8),
                    jax.ShapeDtypeStruct((s, n), jnp.int32),
                    jax.ShapeDtypeStruct((s, n), jnp.float32),
-                   jax.ShapeDtypeStruct((gi, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((gi, 1), jnp.int32)],
+                   jax.ShapeDtypeStruct((gi,), jnp.int32),
+                   jax.ShapeDtypeStruct((gi,), jnp.int32)],
         compiler_params=common.fused_compiler_params(),
         interpret=interpret,
     )(meta, frontier, adj, dist, sigma)

@@ -17,7 +17,7 @@ def vmem_bytes(*, form: str = "dense", bs: int = 128, bn: int = 128,
     if form == "fused":  # whole (n, n) f32 weight matrix + resident state
         return common.fused_vmem_bytes(
             bs=bs, n=n, operand_bytes=n * n * 4,
-            frontier_bytes=bs * n * 1,
+            frontier_bytes=bs * n * (1 + 4),  # i8 rows + f32 fd scratch
             state_itemsizes=(4,),          # dist f32
             out_itemsizes=(1, 4))          # new i8 + dist f32 out
     assert form == "sparse", form
@@ -37,9 +37,9 @@ registry.register(registry.KernelSet(
           "resident — the VMEM gate in resolve_fused_steps bounds n)",
     # sparse only: data-dependent gathers/scatters by edge index are not
     # validated under Mosaic compilation and the whole-(S, n_pad) state is
-    # VMEM-unbounded in n_pad.  The dense form stays compiled-dispatchable:
-    # its per-lane fori_loop/dynamic-slice schedule is the one the boolean
-    # pull kernel has always shipped compiled with.
+    # VMEM-unbounded in n_pad.  The dense form is compiled-dispatchable:
+    # its lane loop is common.lane_fold (aligned chunk loads, static lane
+    # slices), which tests/test_tpu_compile.py compiles for a v5e.
     interpret_only=frozenset({"sparse"}),
     fused_forms={"dense": fused_minplus_multisweep},
 ))

@@ -6,8 +6,9 @@ engine's hot path (paper §5 grown onto the same substrate as BOVM).
   skeleton from ``kernels/common.py``: each (i, j) output tile
   accumulates ``min_k(fdist_block[s, k] + W_block[k, j])`` in a VMEM
   scratch (⊕ = min replaces the MXU add-accumulate; the inner min-plus
-  runs one k lane per VPU step, the same per-lane schedule as the packed
-  pull kernel's word loop), then fuses the DAWN epilogue: improved-mask
+  runs one k lane per VPU step through ``common.lane_fold``, the same
+  schedule as the packed kernels' word loop), then fuses the DAWN
+  epilogue: improved-mask
   test, distance write.  Two scalar-prefetched occupancy tables gate
   every grid step:
 
@@ -61,6 +62,12 @@ from .. import common
 # dense direction: fused min-plus "GEMM" sweep
 # --------------------------------------------------------------------------
 
+def _minplus(acc: jax.Array, col: jax.Array, row: jax.Array) -> jax.Array:
+    """One contraction lane of the (min, +) product: ``col`` (bs, 1)
+    frontier distances plus ``row`` (1, bn) edge weights."""
+    return jnp.minimum(acc, col + row)
+
+
 def _minplus_sweep_kernel(f_occ_ref, o_occ_ref,        # scalar prefetch
                           fd_ref, w_ref, dist_ref,     # VMEM in
                           new_ref, dist_out_ref,       # VMEM out
@@ -76,15 +83,9 @@ def _minplus_sweep_kernel(f_occ_ref, o_occ_ref,        # scalar prefetch
 
     @pl.when(live)
     def _accumulate():
-        fd = fd_ref[...]                       # (bs, bk) f32, +inf off-front
-        w = w_ref[...]                         # (bk, bn) f32, +inf non-edge
-
-        def lane(kk, acc):
-            col = jax.lax.dynamic_slice_in_dim(fd, kk, 1, 1)   # (bs, 1)
-            row = jax.lax.dynamic_slice_in_dim(w, kk, 1, 0)    # (1, bn)
-            return jnp.minimum(acc, col + row)
-
-        acc_ref[...] = jax.lax.fori_loop(0, fd.shape[1], lane, acc_ref[...])
+        # fd (bs, bk) f32, +inf off-frontier; w (bk, bn) f32, +inf non-edge
+        acc_ref[...] = common.lane_fold(fd_ref, w_ref, acc_ref[...],
+                                        _minplus)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -144,24 +145,18 @@ def fused_minplus_sweep(fdist: jax.Array, wdense: jax.Array,
 def _fused_minplus_kernel(meta_ref,                        # scalar prefetch
                           f_ref, w_ref, dist_ref,          # VMEM in
                           new_ref, dist_out_ref,           # VMEM out
-                          prod_ref, stop_ref,              # VMEM out (1, 1)
+                          prod_ref, stop_ref,              # SMEM out (gi,)
+                          fd_ref,                          # VMEM scratch
                           *, max_sweeps: int):
     n_run = meta_ref[1]                  # meta[0] (step) unused: dist is ⊕
-    w = w_ref[...]                       # (n, n) f32, resident throughout
-    d0 = dist_ref[...]                   # (bs, n) f32
+    d0 = dist_ref[...]                   # (bs, n) f32; w (n, n) resident
 
     def sweep(t, carry):
         done, prod, f8, d, new8 = carry
         live = (done == 0) & (t < n_run)
-        fd = jnp.where(f8 != 0, d, jnp.inf)
-
-        def lane(kk, acc):
-            col = jax.lax.dynamic_slice_in_dim(fd, kk, 1, 1)   # (bs, 1)
-            row = jax.lax.dynamic_slice_in_dim(w, kk, 1, 0)    # (1, n)
-            return jnp.minimum(acc, col + row)
-
-        cand = jax.lax.fori_loop(0, w.shape[0], lane,
-                                 jnp.full(d.shape, jnp.inf))
+        fd_ref[...] = jnp.where(f8 != 0, d, jnp.inf)
+        cand = common.lane_fold(fd_ref, w_ref, jnp.full(d.shape, jnp.inf),
+                                _minplus)
         new = cand < d
         any_new = jnp.any(new)
         d = jnp.where(new & live, cand, d)
@@ -177,8 +172,9 @@ def _fused_minplus_kernel(meta_ref,                        # scalar prefetch
          jnp.zeros(d0.shape, jnp.int8)))
     new_ref[...] = new8
     dist_out_ref[...] = d
-    prod_ref[0, 0] = prod
-    stop_ref[0, 0] = done
+    i = pl.program_id(0)
+    prod_ref[i] = prod
+    stop_ref[i] = done
 
 
 @functools.partial(jax.jit,
@@ -205,15 +201,16 @@ def fused_minplus_multisweep(frontier: jax.Array, wdense: jax.Array,
     gi = s // bs
     meta = jnp.stack([jnp.int32(0), jnp.asarray(n_run, jnp.int32)])
 
-    grid_spec = common.fused_grid_spec(gi, bs=bs, n=n, f_block=(bs, n),
-                                       op_block=(n, n))
+    grid_spec = common.fused_grid_spec(
+        gi, bs=bs, n=n, f_block=(bs, n), op_block=(n, n),
+        scratch_shapes=[pltpu.VMEM((bs, n), jnp.float32)])
     new, dist_out, prod, stop = pl.pallas_call(
         functools.partial(_fused_minplus_kernel, max_sweeps=max_sweeps),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((s, n), jnp.int8),
                    jax.ShapeDtypeStruct((s, n), jnp.float32),
-                   jax.ShapeDtypeStruct((gi, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((gi, 1), jnp.int32)],
+                   jax.ShapeDtypeStruct((gi,), jnp.int32),
+                   jax.ShapeDtypeStruct((gi,), jnp.int32)],
         compiler_params=common.fused_compiler_params(),
         interpret=interpret,
     )(meta, frontier, wdense, dist)
@@ -304,7 +301,7 @@ def sparse_relax_sweep(frontier: jax.Array, dist: jax.Array,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((s, n_pad), jnp.int8),
                    jax.ShapeDtypeStruct((s, n_pad), jnp.float32)],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(frontier, dist, src2, dst2, w2)

@@ -13,13 +13,10 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-
-from ..compat import AxisType
+from jax.sharding import AxisType
 
 
 def _axis_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

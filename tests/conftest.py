@@ -1,8 +1,20 @@
-"""Shared test fixtures."""
-import numpy as np
-import pytest
+"""Shared test fixtures.
 
-from repro.graph.csr import CSRGraph
+The suite runs on the CPU, with the Pallas kernels in interpret mode,
+whatever accelerator the machine holds: ``JAX_PLATFORMS`` defaults to
+``cpu`` here, before anything imports JAX, and the tests that start
+child processes pass it to them.  The chip is driven by
+``chip_smoke.py``; ``tests/test_tpu_compile.py`` compiles for a described
+v5e without one.
+"""
+import os
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+from repro.graph.csr import CSRGraph  # noqa: E402
 
 
 @pytest.fixture

@@ -5,6 +5,7 @@ package: the unified ``dawn`` facade plus the dynamic-graph types it
 fronts.  Growing it is an API decision — update the snapshot in the
 same PR and say why — not a side effect of an import added somewhere.
 """
+import os
 import subprocess
 import sys
 import warnings
@@ -38,7 +39,8 @@ def test_importing_repro_does_not_touch_attic():
             "bad = [m for m in sys.modules if m.startswith('repro._attic')]; "
             "assert not bad, bad; print('clean')")
     out = subprocess.run([sys.executable, "-c", code],
-                         capture_output=True, text=True)
+                         capture_output=True, text=True,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert out.returncode == 0, out.stderr
     assert "clean" in out.stdout
 
@@ -70,7 +72,8 @@ for fn, args in ((apsp_engine, (g, [0])),
 print('once-each')
 """
     out = subprocess.run([sys.executable, "-c", code],
-                         capture_output=True, text=True)
+                         capture_output=True, text=True,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert out.returncode == 0, out.stderr
     assert "once-each" in out.stdout
 

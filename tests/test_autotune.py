@@ -335,3 +335,23 @@ def test_facade_tune_and_reload(tmp_path):
     np.testing.assert_array_equal(np.asarray(r1.dist), np.asarray(r2.dist))
     np.testing.assert_array_equal(np.asarray(r1.direction_counts),
                                   np.asarray(r2.direction_counts))
+
+
+# --------------------------------------------------------------------------
+# backend profiles: keyed on the TPU's device_kind, no silent default
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("fingerprint,peak", [
+    ("tpu:TPU v5 lite", 197e12),
+    ("cpu:cpu", 2.0e11),
+])
+def test_backend_profile_known_devices(fingerprint, peak):
+    prof = backend_profile(fingerprint)
+    assert prof.name == fingerprint and prof.peak_flops == peak
+
+
+@pytest.mark.parametrize("fingerprint", ["tpu:TPU v9 imaginary",
+                                         "gpu:NVIDIA H100"])
+def test_backend_profile_unknown_device_is_an_error(fingerprint):
+    with pytest.raises(ValueError, match="no roofline profile"):
+        backend_profile(fingerprint)

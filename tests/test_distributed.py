@@ -1,5 +1,6 @@
 """Distribution tests on virtual devices (subprocess: jax must initialize
 with --xla_force_host_platform_device_count before first use)."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -14,7 +15,8 @@ def _run(body: str, devices: int = 8):
             "--xla_force_host_platform_device_count={devices}"
     """) + textwrap.dedent(body)
     res = subprocess.run([sys.executable, "-c", script],
-                         capture_output=True, text=True, timeout=900)
+                         capture_output=True, text=True, timeout=900,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert res.returncode == 0, res.stderr[-4000:]
     return res.stdout
 
@@ -255,8 +257,7 @@ def test_sharded_lm_train_step_matches_single_device():
         pspec = T.param_specs(cfg)
         sspec = opt.state_specs(pspec)
         bspec = {"tokens": P("data", None), "labels": P("data", None)}
-        from repro import compat
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             jstep = jax.jit(step,
                             in_shardings=shardings(mesh, (pspec, sspec,
                                                           bspec)),
@@ -285,8 +286,7 @@ def test_embed_lookup_sharded_equals_local():
         table = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
         toks = jax.random.randint(jax.random.PRNGKey(1), (4, 6), 0, 64)
         ref = table[toks]
-        from repro import compat
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             t = jax.device_put(table, NamedSharding(mesh, P(None, "model")))
             k = jax.device_put(toks, NamedSharding(mesh, P("data", None)))
             got = jax.jit(lambda a, b: embed_lookup(a, b, jnp.float32))(t, k)
@@ -310,8 +310,7 @@ def test_compressed_cross_pod_psum():
         def f(v):
             return psum_c(v)
 
-        from repro import compat
-        got = compat.shard_map(f, mesh=mesh,
+        got = jax.shard_map(f, mesh=mesh,
                             in_specs=jax.sharding.PartitionSpec("pod"),
                             out_specs=jax.sharding.PartitionSpec("pod"))(x)
         ref = jnp.broadcast_to(x.sum(0, keepdims=True), x.shape)

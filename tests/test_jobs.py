@@ -259,7 +259,8 @@ def _run(body: str, devices: int = 8):
             "--xla_force_host_platform_device_count={devices}"
     """) + textwrap.dedent(body)
     res = subprocess.run([sys.executable, "-c", script],
-                         capture_output=True, text=True, timeout=900)
+                         capture_output=True, text=True, timeout=900,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert res.returncode == 0, res.stderr[-4000:]
     return res.stdout
 

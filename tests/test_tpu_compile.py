@@ -1,0 +1,157 @@
+"""Compile the chip path for a TPU v5e that is described, not attached.
+
+Interpret mode (what every other kernel test runs) accepts shapes and
+primitives that Mosaic refuses: a dynamic slice along lanes, a block
+whose last dim is neither a multiple of 128 nor the whole width, a
+scalar stored to VMEM, more scoped VMEM than the kernel may use.  These
+tests compile each kernel the TPU path dispatches, at the widths
+``chip_smoke.py`` runs, plus the Graph500 scale-20 sparse batch program,
+whose device memory must fit one 16 GB chip.  Nothing runs; a compile
+that passes here is not a chip run.
+
+The topology is described inside a fixture, never at import time: only
+one process may load the TPU library, and pytest-xdist workers import
+every test file.
+"""
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.engine import EngineConfig, _run_batch
+from repro.core.sweep import SPARSE
+from repro.kernels import common
+from repro.kernels.bovm import (fused_boolean_multisweep, packed_pull_sweep,
+                                packed_push_sweep)
+from repro.kernels.counting import (fused_counting_multisweep,
+                                    fused_counting_sweep)
+from repro.kernels.tropical import (fused_minplus_multisweep,
+                                    fused_minplus_sweep)
+
+# n_pad = round_up(n + 1, 128) of the graphs chip_smoke.py runs
+GRID_ROAD_MD = 32512       # SUITE grid_road_md, 180 x 180 = 32,400 vertices
+RMAT_SOCIAL_MD = 8320      # SUITE rmat_social_md, 2^13 vertices
+GRID_ROAD_SM = 4224        # SUITE grid_road_sm, 64 x 64: the fused phase
+FUSED_DENSE = 1152         # largest f32/int8 whole-operand fused width used
+S = 128                    # sources per tile in the kernel phase
+
+# Graph500 scale 20, edge factor 16, undirected: 2^25 lanes before dedup
+SCALE20_N = 1 << 20
+SCALE20_LANES = 1 << 25
+SCALE20_KEYS = 64
+V5E_HBM_BYTES = 15.75 * 2 ** 30      # what the v5e compiler may allocate
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be cached but never read back
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args, **static):
+    compiled = fn.lower(*args, **static).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n_pad", [GRID_ROAD_MD, RMAT_SOCIAL_MD])
+@pytest.mark.parametrize("direction", ["push", "pull"])
+def test_packed_kernels_compile(one_chip, n_pad, direction):
+    """The engine's packed push (bs = 128) and pull (bs = 8) sweeps at
+    the word tile ``common.word_tile`` picks — the whole width here,
+    since n_pad / 32 is not a multiple of 128 for either graph."""
+    words = n_pad // 32
+    wk = common.word_tile(words)
+    assert wk == words
+    kern, bs = ((packed_push_sweep, 128) if direction == "push"
+                else (packed_pull_sweep, 8))
+    _compile(kern, _spec(one_chip, (S, words), jnp.uint32),
+             _spec(one_chip, (n_pad, words), jnp.uint32),
+             _spec(one_chip, (S, n_pad), jnp.int32),
+             _spec(one_chip, (), jnp.int32),
+             bs=bs, bn=128, wk=wk, interpret=False)
+
+
+def test_minplus_dense_compiles(one_chip):
+    n = RMAT_SOCIAL_MD
+    _compile(fused_minplus_sweep, _spec(one_chip, (S, n), jnp.float32),
+             _spec(one_chip, (n, n), jnp.float32),
+             _spec(one_chip, (S, n), jnp.float32),
+             _spec(one_chip, (), jnp.float32),
+             bs=128, bn=128, bk=128, interpret=False)
+
+
+def test_counting_push_compiles(one_chip):
+    n = RMAT_SOCIAL_MD
+    _compile(fused_counting_sweep, _spec(one_chip, (S, n), jnp.float32),
+             _spec(one_chip, (n, n), jnp.int8),
+             _spec(one_chip, (S, n), jnp.int32),
+             _spec(one_chip, (S, n), jnp.float32),
+             _spec(one_chip, (), jnp.int32),
+             bs=128, bn=128, bk=128, interpret=False)
+
+
+def test_fused_boolean_multisweep_compiles(one_chip):
+    n = GRID_ROAD_SM
+    _compile(fused_boolean_multisweep, _spec(one_chip, (S, n), jnp.int8),
+             _spec(one_chip, (n, n // 32), jnp.uint32),
+             _spec(one_chip, (S, n), jnp.int32),
+             _spec(one_chip, (), jnp.int32), _spec(one_chip, (), jnp.int32),
+             bs=128, max_sweeps=n, interpret=False)
+
+
+def test_fused_minplus_multisweep_compiles(one_chip):
+    n = FUSED_DENSE
+    _compile(fused_minplus_multisweep, _spec(one_chip, (S, n), jnp.int8),
+             _spec(one_chip, (n, n), jnp.float32),
+             _spec(one_chip, (S, n), jnp.float32),
+             _spec(one_chip, (), jnp.int32), _spec(one_chip, (), jnp.int32),
+             bs=128, max_sweeps=n, interpret=False)
+
+
+def test_fused_counting_multisweep_compiles(one_chip):
+    n = FUSED_DENSE
+    _compile(fused_counting_multisweep, _spec(one_chip, (S, n), jnp.int8),
+             _spec(one_chip, (n, n), jnp.int8),
+             (_spec(one_chip, (S, n), jnp.int32),
+              _spec(one_chip, (S, n), jnp.float32)),
+             _spec(one_chip, (), jnp.int32), _spec(one_chip, (), jnp.int32),
+             bs=128, max_sweeps=n, interpret=False)
+
+
+def test_graph500_scale20_sparse_batch_fits_one_chip(one_chip):
+    """The whole ``_run_batch`` program of ``prepare(g, mode="sparse",
+    source_batch=64).apsp(keys)`` at Graph500 scale 20: the edge-lane
+    gather/scatter state must fit the chip's HBM."""
+    n_pad = -(-(SCALE20_N + 1) // 128) * 128
+    cfg = EngineConfig(mode="sparse", source_batch=SCALE20_KEYS)
+    compiled = _run_batch.lower(
+        _spec(one_chip, (1, 1), jnp.int8),
+        _spec(one_chip, (1, 1), jnp.uint32),
+        _spec(one_chip, (SCALE20_LANES,), jnp.int32),
+        _spec(one_chip, (SCALE20_LANES,), jnp.int32),
+        _spec(one_chip, (n_pad,), jnp.float32),
+        _spec(one_chip, (SCALE20_KEYS,), jnp.int32),
+        _spec(one_chip, (), jnp.int32),
+        cfg=cfg, n_real=SCALE20_N, n_pad=n_pad, max_steps=SCALE20_N,
+        use_kernel=True, interpret=False, forced_dir=SPARSE,
+        fused_steps=0).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < used <= V5E_HBM_BYTES, used / 2 ** 30
