@@ -39,6 +39,7 @@ from typing import Optional, Sequence, Union
 
 import numpy as np
 
+from . import obs
 from .core.autotune import TuningPlan, build_plan
 from .core.centrality import (MEASURES, CentralityConfig, CentralityResult,
                               centrality as _centrality)
@@ -185,31 +186,35 @@ class DawnGraph:
         distances.
         """
         self._check_semiring(semiring)
-        if checkpoint_dir is not None or on_chunk is not None:
-            from .core.jobs import run_sweep_job
-            return run_sweep_job(
-                self.graph, sources, workload=semiring,
-                weights=self._lane_weights()
-                if semiring == "tropical" else None,
-                mesh=mesh, options=self.options, chunk_size=chunk_size,
-                checkpoint_dir=checkpoint_dir,
-                checkpoint_interval=checkpoint_interval, resume=resume,
-                on_chunk=on_chunk)
-        if mesh is not None:
-            # config is baked into the prepared operands (_sharded_operands)
-            return _sharded_apsp(self._sharded_operands(semiring, mesh),
-                                 sources)
-        if semiring == "boolean":
-            return _apsp_engine(self.prepared(), sources,
-                                config=self.options.to(EngineConfig,
-                                                       lenient=True))
-        if semiring == "tropical":
-            return _weighted_apsp(self.prepared_weighted(), sources=sources,
-                                  config=self.options.to(WeightedConfig,
+        n_sources = self.graph.n_nodes if sources is None else len(sources)
+        with obs.span("apsp", semiring=semiring, n_sources=n_sources):
+            if checkpoint_dir is not None or on_chunk is not None:
+                from .core.jobs import run_sweep_job
+                return run_sweep_job(
+                    self.graph, sources, workload=semiring,
+                    weights=self._lane_weights()
+                    if semiring == "tropical" else None,
+                    mesh=mesh, options=self.options, chunk_size=chunk_size,
+                    checkpoint_dir=checkpoint_dir,
+                    checkpoint_interval=checkpoint_interval, resume=resume,
+                    on_chunk=on_chunk)
+            if mesh is not None:
+                # config is baked into the prepared operands
+                # (_sharded_operands)
+                return _sharded_apsp(self._sharded_operands(semiring, mesh),
+                                     sources)
+            if semiring == "boolean":
+                return _apsp_engine(self.prepared(), sources,
+                                    config=self.options.to(EngineConfig,
+                                                           lenient=True))
+            if semiring == "tropical":
+                return _weighted_apsp(self.prepared_weighted(),
+                                      sources=sources,
+                                      config=self.options.to(WeightedConfig,
+                                                             lenient=True))
+            return _counting_apsp(self.prepared(), sources,
+                                  config=self.options.to(CentralityConfig,
                                                          lenient=True))
-        return _counting_apsp(self.prepared(), sources,
-                              config=self.options.to(CentralityConfig,
-                                                     lenient=True))
 
     def sssp(self, source: int, *, semiring: str = "boolean",
              mesh=None) -> np.ndarray:

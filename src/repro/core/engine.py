@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..graph.csr import CSRGraph
 from . import autotune
 from . import sweep as S
@@ -234,16 +235,17 @@ def _run_batch(adj, adj_pull, src_idx, dst_idx, deg, sources, n_valid, *,
     m_pad = src_idx.shape[0]
     bs = min(s, 128)
 
-    f0 = one_hot_frontier(sources, n_pad, dtype=jnp.int8)
-    # padded source rows (>= n_valid) start with an empty frontier and a
-    # fully-visited dist: they do no work, add nothing to the Eq. 10
-    # counters, and never extend the while_loop past the real rows
-    row_ok = (jnp.arange(s) < n_valid)[:, None]
-    f0 = jnp.where(row_ok, f0, 0)
-    dist0 = jnp.where(f0 != 0, 0, jnp.full((s, n_pad), UNREACHED))
-    # pad columns are born "visited" so no sweep form ever discovers them
-    dist0 = jnp.where(row_ok & (jnp.arange(n_pad)[None, :] < n_real),
-                      dist0, 0)
+    with obs.scope("batch.init"):
+        f0 = one_hot_frontier(sources, n_pad, dtype=jnp.int8)
+        # padded source rows (>= n_valid) start with an empty frontier and
+        # a fully-visited dist: they do no work, add nothing to the Eq. 10
+        # counters, and never extend the while_loop past the real rows
+        row_ok = (jnp.arange(s) < n_valid)[:, None]
+        f0 = jnp.where(row_ok, f0, 0)
+        dist0 = jnp.where(f0 != 0, 0, jnp.full((s, n_pad), UNREACHED))
+        # pad columns are born "visited" so no sweep form discovers them
+        dist0 = jnp.where(row_ok & (jnp.arange(n_pad)[None, :] < n_real),
+                          dist0, 0)
 
     forms = S.boolean_forms(adj, adj_pull, src_idx, dst_idx, n_pad=n_pad,
                             s=s, bn=cfg.bn, bk=cfg.bk,
@@ -346,60 +348,64 @@ def apsp_engine_blocks(
 ) -> Iterator[Tuple[np.ndarray, jax.Array, SweepState]]:
     """Stream (source_ids, dist_rows, raw_sweep_state) one source tile at a
     time — the non-materializing form for large n."""
-    pg = g if isinstance(g, PreparedGraph) else prepare_graph(g)
-    # TuningPlan overlay (no-op without one): tiles clamped to this
-    # graph's padding, fused gate, cost constants
-    config = autotune.apply(config, semiring="boolean", n_pad=pg.n_pad)
-    graph = pg.graph
-    n = graph.n_nodes
-    srcs = np.arange(n, dtype=np.int32) if sources is None else \
-        np.asarray(sources, np.int32)
-    if srcs.size == 0:
-        raise ValueError("apsp_engine: empty source list")
-    if srcs.min() < 0 or srcs.max() >= n:
-        raise ValueError(
-            f"apsp_engine: sources must be in [0, {n}), got "
-            f"[{srcs.min()}, {srcs.max()}]")
-    use_kernel, interpret = _resolve_kernel(config)
-    max_steps = config.max_steps or n
-    B = config.source_batch
-    forced_dir = _resolve_direction(pg, B, config, use_kernel, interpret)
-    # fused multi-sweep blocks only exist on the kernel push path; the
-    # resolver returns None (-> per-sweep loop) whenever the capability is
-    # missing or the whole-operand residency would blow the VMEM budget
-    fused_steps = 0
-    if config.fused_steps and forced_dir in (None, PUSH):
-        fused_steps = S.resolve_fused_steps(
-            "boolean", "push", fused_steps=config.fused_steps,
-            max_steps=max_steps, use_kernel=use_kernel, n_pad=pg.n_pad,
-            bs=min(B, 128),
-            budget=None if config.tuning is None
-            else config.tuning.vmem_budget) or 0
-        if fused_steps:
-            forced_dir = PUSH   # fused blocks pin one direction
-    # only materialize the O(n_pad^2) operands the resolved direction can
-    # dispatch; the other slot gets a (1, 1) dummy its closure never
-    # traces.  The kernel path runs *both* dense directions (and the
-    # fused block) off the bit-packed pull operand; the dense int8
-    # adjacency only feeds the XLA reference push.
-    adj = pg.adj if (forced_dir in (None, PUSH) and not use_kernel) else \
-        jnp.zeros((1, 1), jnp.int8)
-    adj_pull = pg.adj_pull if (
-        forced_dir in (None, PULL)
-        or (forced_dir in (None, PUSH) and use_kernel)) else \
-        jnp.zeros((1, 1), jnp.uint32)
+    with obs.span("engine.plan"):
+        pg = g if isinstance(g, PreparedGraph) else prepare_graph(g)
+        # TuningPlan overlay (no-op without one): tiles clamped to this
+        # graph's padding, fused gate, cost constants
+        config = autotune.apply(config, semiring="boolean", n_pad=pg.n_pad)
+        graph = pg.graph
+        n = graph.n_nodes
+        srcs = np.arange(n, dtype=np.int32) if sources is None else \
+            np.asarray(sources, np.int32)
+        if srcs.size == 0:
+            raise ValueError("apsp_engine: empty source list")
+        if srcs.min() < 0 or srcs.max() >= n:
+            raise ValueError(
+                f"apsp_engine: sources must be in [0, {n}), got "
+                f"[{srcs.min()}, {srcs.max()}]")
+        use_kernel, interpret = _resolve_kernel(config)
+        max_steps = config.max_steps or n
+        B = config.source_batch
+        forced_dir = _resolve_direction(pg, B, config, use_kernel, interpret)
+        # fused multi-sweep blocks only exist on the kernel push path; the
+        # resolver returns None (-> per-sweep loop) whenever the capability
+        # is missing or the whole-operand residency would blow the VMEM
+        # budget
+        fused_steps = 0
+        if config.fused_steps and forced_dir in (None, PUSH):
+            fused_steps = S.resolve_fused_steps(
+                "boolean", "push", fused_steps=config.fused_steps,
+                max_steps=max_steps, use_kernel=use_kernel, n_pad=pg.n_pad,
+                bs=min(B, 128),
+                budget=None if config.tuning is None
+                else config.tuning.vmem_budget) or 0
+            if fused_steps:
+                forced_dir = PUSH   # fused blocks pin one direction
+        # only materialize the O(n_pad^2) operands the resolved direction
+        # can dispatch; the other slot gets a (1, 1) dummy its closure
+        # never traces.  The kernel path runs *both* dense directions (and
+        # the fused block) off the bit-packed pull operand; the dense int8
+        # adjacency only feeds the XLA reference push.
+        adj = pg.adj if (forced_dir in (None, PUSH) and not use_kernel) \
+            else jnp.zeros((1, 1), jnp.int8)
+        adj_pull = pg.adj_pull if (
+            forced_dir in (None, PULL)
+            or (forced_dir in (None, PUSH) and use_kernel)) else \
+            jnp.zeros((1, 1), jnp.uint32)
     for lo in range(0, len(srcs), B):
         block = srcs[lo: lo + B]
         valid = len(block)
-        padded = np.zeros(B, np.int32)
-        padded[:valid] = block
-        st = _run_batch(adj, adj_pull, pg.graph.src, pg.graph.dst,
-                        pg.deg, jnp.asarray(padded), jnp.int32(valid),
-                        cfg=config, n_real=n, n_pad=pg.n_pad,
-                        max_steps=max_steps,
-                        use_kernel=use_kernel, interpret=interpret,
-                        forced_dir=forced_dir, fused_steps=fused_steps)
-        yield block, st.dist[:valid, :n], st
+        with obs.span("engine.tile", valid=valid, tile=B):
+            padded = np.zeros(B, np.int32)
+            padded[:valid] = block
+            st = _run_batch(adj, adj_pull, pg.graph.src, pg.graph.dst,
+                            pg.deg, jnp.asarray(padded), jnp.int32(valid),
+                            cfg=config, n_real=n, n_pad=pg.n_pad,
+                            max_steps=max_steps,
+                            use_kernel=use_kernel, interpret=interpret,
+                            forced_dir=forced_dir, fused_steps=fused_steps)
+            rows = st.dist[:valid, :n]
+        yield block, rows, st
 
 
 def apsp_engine(g: Union[CSRGraph, PreparedGraph],
@@ -410,14 +416,17 @@ def apsp_engine(g: Union[CSRGraph, PreparedGraph],
     Returns distances for every requested source (default: all nodes),
     plus sweep/direction/work counters aggregated over source tiles.
     """
-    rows = []
-    sweeps = jnp.int32(0)
-    counts = jnp.zeros(3, jnp.int32)
-    touched = jnp.float32(0.0)
+    rows, tallies = [], []
     for _, dist, st in apsp_engine_blocks(g, sources, config=config):
         rows.append(dist)
-        sweeps = jnp.maximum(sweeps, st.step)
-        counts = counts + st.dir_counts
-        touched = touched + st.edges_touched
-    return ApspResult(dist=jnp.concatenate(rows, axis=0), sweeps=sweeps,
-                      direction_counts=counts, edges_touched=touched)
+        tallies.append((st.step, st.dir_counts, st.edges_touched))
+    with obs.span("engine.collect"):
+        sweeps = jnp.int32(0)
+        counts = jnp.zeros(3, jnp.int32)
+        touched = jnp.float32(0.0)
+        for step, dir_counts, edges_touched in tallies:
+            sweeps = jnp.maximum(sweeps, step)
+            counts = counts + dir_counts
+            touched = touched + edges_touched
+        return ApspResult(dist=jnp.concatenate(rows, axis=0), sweeps=sweeps,
+                          direction_counts=counts, edges_touched=touched)

@@ -46,6 +46,7 @@ from typing import Any, Callable, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..kernels import common as kernel_common
 from ..kernels import registry as kernel_registry
 from .frontier import UNREACHED, pack_bits
@@ -173,8 +174,12 @@ def sweep_loop(forms: Sequence[SweepForm], state: SweepState, *,
                  ``(prod, stopped)`` pair (pmax / psum-all) so every
                  shard of the distributed executor agrees on the loop
                  accounting — the fused analogue of ``converged``.
+
+    Each form call, the fused block, ``choose`` and the convergence test
+    trace under a ``dawn.sweep.*`` scope (:mod:`repro.obs`), so a device
+    trace attributes every sweep operation to its form.
     """
-    forms = tuple(forms)
+    forms = tuple(obs.scoped_form(f) for f in forms)
 
     def cond(st: SweepState):
         return (~st.done) & (st.step < max_steps)
@@ -185,10 +190,11 @@ def sweep_loop(forms: Sequence[SweepForm], state: SweepState, *,
         def body(st: SweepState):
             n_run = jnp.minimum(jnp.asarray(fused_steps, jnp.int32),
                                 jnp.asarray(max_steps, jnp.int32) - st.step)
-            new, dist, prod, stopped = fused(st.frontier, st.dist,
-                                             st.step, n_run)
-            if fused_combine is not None:
-                prod, stopped = fused_combine(prod, stopped)
+            with obs.scope("sweep.fused"):
+                new, dist, prod, stopped = fused(st.frontier, st.dist,
+                                                 st.step, n_run)
+                if fused_combine is not None:
+                    prod, stopped = fused_combine(prod, stopped)
             executed = jnp.where(stopped, prod + 1, n_run)
             return SweepState(
                 frontier=new, dist=dist, parent=st.parent,
@@ -205,17 +211,19 @@ def sweep_loop(forms: Sequence[SweepForm], state: SweepState, *,
                 new, dist, parent = forms[forced_dir](st.frontier, st.dist,
                                                       st.parent, step)
             else:
-                idx = choose(st)
+                with obs.scope("sweep.choose"):
+                    idx = choose(st)
                 new, dist, parent = jax.lax.switch(idx, forms, st.frontier,
                                                    st.dist, st.parent, step)
-            if converged is None:
-                stop = ~jnp.any(new != 0)
-            else:
-                stop = converged(new)
-            touched = st.edges_touched
-            if deg is not None:
-                touched = touched + jnp.sum(
-                    (st.frontier != 0).astype(jnp.float32) * deg)
+            with obs.scope("sweep.test"):
+                if converged is None:
+                    stop = ~jnp.any(new != 0)
+                else:
+                    stop = converged(new)
+                touched = st.edges_touched
+                if deg is not None:
+                    touched = touched + jnp.sum(
+                        (st.frontier != 0).astype(jnp.float32) * deg)
             return SweepState(
                 frontier=new, dist=dist, parent=parent, step=step, done=stop,
                 sweeps=jnp.where(stop, st.sweeps, step),
@@ -480,11 +488,11 @@ def minlabel_form(src_idx, dst_idx) -> SweepForm:
     ⊕ = min.  Pass symmetrized edge arrays for *weakly* connected
     components.  The frontier is the changed-label set; Fact 1 is "no
     label lowered"."""
-    def sweep(f, labels, p, step):
+    def min_label(f, labels, p, step):
         nl = labels.at[..., dst_idx].min(labels[..., src_idx])
         changed = nl < labels
         return changed.astype(jnp.int8), nl, p
-    return sweep
+    return min_label
 
 
 # --------------------------------------------------------------------------

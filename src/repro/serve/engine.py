@@ -36,8 +36,10 @@ import time
 from collections import OrderedDict, deque
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
+from .. import obs
 from ..core.centrality import (MEASURES, CentralityConfig, betweenness,
                                centrality)
 from ..core.distributed import (ShardedConfig, ShardedOperands,
@@ -330,6 +332,7 @@ class GraphService:
 
     # -- admission ---------------------------------------------------------
 
+    @obs.spanned("serve.submit")
     def submit(self, query: GraphQuery):
         """Validate, then answer from the cache/oracle tier or enqueue.
 
@@ -474,6 +477,7 @@ class GraphService:
         batch = self._take_global(self.max_batch)
         return self._serve(batch)
 
+    @obs.spanned("serve.tick")
     def tick(self) -> List[GraphQuery]:
         """Deadline-aware flush: serve ONE ripe bucket (FIFO within it),
         or nothing if no bucket is ripe.
@@ -542,6 +546,24 @@ class GraphService:
                 live.append(q)
         if not live:
             return batch
+        with obs.span("serve.flush", rows=len(live),
+                      tile=self.config.source_batch,
+                      wait_ms=1e3 * (now - min(q.t_submit for q in live))):
+            self._flush_live(live)
+        return batch
+
+    @staticmethod
+    def _rows_to_host(dist) -> np.ndarray:
+        """A flush's distance rows, waited for on the device, then copied
+        to the host."""
+        with obs.span("serve.flush.wait"):
+            jax.block_until_ready(dist)
+        with obs.span("serve.flush.copy"):
+            return np.asarray(dist)
+
+    def _flush_live(self, live: List[GraphQuery]) -> None:
+        """Run the sweep micro-batches of one flush and complete its
+        queries."""
         # measured with the injected clock so the EWMA below shares a
         # time scale with deadlines/ripeness under a virtual clock
         t0 = self._clock()
@@ -552,37 +574,37 @@ class GraphService:
         if unweighted:
             sources = np.asarray([q.source for q in unweighted], np.int32)
             if self._route_sharded(len(unweighted)):
-                dist = np.asarray(
-                    sharded_apsp(self._sharded_operands("boolean"),
-                                 sources).dist)
+                dist = sharded_apsp(self._sharded_operands("boolean"),
+                                    sources).dist
                 self.sharded_flushes += 1
                 served_by = "sharded"
             else:
                 (_, dist, _), = apsp_engine_blocks(self.prepared, sources,
                                                    config=self.config)
-                dist = np.asarray(dist)
                 served_by = "sweep"
-            for row, q in zip(dist, unweighted):
-                self._fill_from_row(q, row)
-                self._cache_row("unweighted", q.source, row)
-                q.served_by = served_by
+            dist = self._rows_to_host(dist)
+            with obs.span("serve.flush.fill"):
+                for row, q in zip(dist, unweighted):
+                    self._fill_from_row(q, row)
+                    self._cache_row("unweighted", q.source, row)
+                    q.served_by = served_by
         if weighted:
             sources = np.asarray([q.source for q in weighted], np.int32)
             if self._route_sharded(len(weighted)):
-                dist = np.asarray(
-                    sharded_apsp(self._sharded_operands("tropical"),
-                                 sources).dist)
+                dist = sharded_apsp(self._sharded_operands("tropical"),
+                                    sources).dist
                 self.sharded_flushes += 1
                 served_by = "sharded"
             else:
-                res = weighted_apsp(self.prepared_weighted, sources=sources,
-                                    config=self.weighted_config)
-                dist = np.asarray(res.dist)
+                dist = weighted_apsp(self.prepared_weighted, sources=sources,
+                                     config=self.weighted_config).dist
                 served_by = "sweep"
-            for row, q in zip(dist, weighted):
-                self._fill_from_row(q, row)
-                self._cache_row("weighted", q.source, row)
-                q.served_by = served_by
+            dist = self._rows_to_host(dist)
+            with obs.span("serve.flush.fill"):
+                for row, q in zip(dist, weighted):
+                    self._fill_from_row(q, row)
+                    self._cache_row("weighted", q.source, row)
+                    q.served_by = served_by
         if analytics:
             self._flush_analytics(analytics)
             for q in analytics:
@@ -601,7 +623,6 @@ class GraphService:
                 len(self.completed) > self.completed_retention:
             del self.completed[: len(self.completed)
                                - self.completed_retention]
-        return batch
 
     def _flush_analytics(self, queries: List[GraphQuery]) -> None:
         """Serve one micro-batch of centrality queries: all per-source
