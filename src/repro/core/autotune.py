@@ -409,8 +409,8 @@ def _hlo_unit_costs(pg, profile: BackendProfile, *, weights, s: int
     out: Dict[Tuple[str, str], float] = {}
 
     f0, dist = _representative_state(s, n_pad, np.int32, int(UNREACHED), 1)
-    bool_forms = S.boolean_forms(pg.adj, pg.adj_pull, g.src, g.dst,
-                                 n_pad=n_pad, s=s)
+    bool_forms = S.boolean_forms(pg.adj, pg.adj_pull, pg.rows, n_pad=n_pad,
+                                 s=s)
     for name, form in zip(FORM_VOCAB["boolean"], bool_forms):
         t = _form_seconds(form, f0, dist, profile)
         if t is not None:

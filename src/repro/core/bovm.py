@@ -83,9 +83,8 @@ def bovm_msbfs(adj: jax.Array, sources: jax.Array, *,
     # dense boolean PUSH only: the pull/sparse slots get dummies that the
     # pinned forced_dir never traces
     push, _, _ = S.boolean_forms(
-        adj, jnp.zeros((1, 1), jnp.uint32), jnp.zeros(1, jnp.int32),
-        jnp.zeros(1, jnp.int32), n_pad=n, s=s, use_kernel=False,
-        accum_dtype=accum_dtype)
+        adj, jnp.zeros((1, 1), jnp.uint32), None, n_pad=n, s=s,
+        use_kernel=False, accum_dtype=accum_dtype)
 
     st = S.sweep_loop((push,), S.make_state(f0, dist0, n_forms=1),
                       max_steps=max_steps, deg=deg)

@@ -15,7 +15,9 @@ semiring the sweep layer knows:
   * **vertices** (optional, mesh axis ``model``) shard the sweep operand:
     the dense adjacency / weight matrix splits into K-row blocks (the
     contraction dim), the CSR lanes into per-shard dst-block partitions
-    (:func:`repro.graph.partition.edge_partition_global`).  Each sweep
+    (:func:`repro.graph.partition.edge_partition_global`; the boolean
+    sparse form reads each partition as destination rows,
+    :func:`repro.core.sweep.dst_rows`).  Each sweep
     computes a *partial* candidate set from its local block and
     cross-shard combines with the semiring's ⊕ — OR (``lax.pmax``) for
     boolean, min (``lax.pmin``) for tropical, masked ADD (``lax.psum``
@@ -150,7 +152,12 @@ class ShardedOperands:
     dense_op: jax.Array      # (n_pad, n_pad) adj int8 / weights f32,
     #                          K-row-sharded over model; (1, 1) dummy
     src_l: jax.Array         # (C, e_pad) sharded / (m_pad,) replicated
-    dst_l: jax.Array         #   global ids, CSR sentinel n
+    dst_l: jax.Array         #   global ids, CSR sentinel n; (1,) dummies
+    #                          on the boolean semiring, which reads rows
+    row_src: jax.Array       # boolean sparse operand (sweep.dst_rows):
+    row_dst: jax.Array       #   (C, W, R) / (C, R) sharded, one layout per
+    #                          lane partition, or (W, R) / (R,)
+    #                          replicated; (1, 1) / (1,) dummies
     w_l: jax.Array           # tropical lane weights (+inf pad); (1,) dummy
     w_min: jax.Array         # scalar f32 min finite edge weight (0 dummy)
     deg: jax.Array           # (n_pad,) f32 out-degrees, replicated (0 pad)
@@ -223,25 +230,43 @@ def prepare_sharded(g: CSRGraph, mesh: Mesh, *, weights=None,
 
     src_l = dst_l = jnp.zeros((1,), jnp.int32)
     w_l = jnp.zeros((1,), jnp.float32)
+    row_src, row_dst = jnp.zeros((1, 1), jnp.int32), \
+        jnp.zeros((1,), jnp.int32)
+    boolean = config.semiring == "boolean"
     m_local = g.m_pad
     if config.need_sparse:
         if C > 1:
             parts = edge_partition_global(g, C, weights=lanes)
-            lane_sharding = NamedSharding(mesh, P(MODEL_AXIS, None))
-            src_l = jax.device_put(parts["src"], lane_sharding)
-            dst_l = jax.device_put(parts["dst"], lane_sharding)
-            if tropical:
-                w_l = jax.device_put(parts["w"], lane_sharding)
             m_local = parts["e_pad"]
+            if boolean:
+                rows = [_partition_rows(src, dst, g.n_nodes) for src, dst
+                        in zip(np.asarray(parts["src"]),
+                               np.asarray(parts["dst"]))]
+                row_src = jax.device_put(
+                    jnp.stack([r for r, _ in rows]),
+                    NamedSharding(mesh, P(MODEL_AXIS, None, None)))
+                row_dst = jax.device_put(
+                    jnp.stack([r for _, r in rows]),
+                    NamedSharding(mesh, P(MODEL_AXIS, None)))
+            else:
+                lane_sharding = NamedSharding(mesh, P(MODEL_AXIS, None))
+                src_l = jax.device_put(parts["src"], lane_sharding)
+                dst_l = jax.device_put(parts["dst"], lane_sharding)
+                if tropical:
+                    w_l = jax.device_put(parts["w"], lane_sharding)
         else:
             # replicated on every device of the mesh, not left on the
             # default device for each call to copy out
             replicated = NamedSharding(mesh, P())
-            src_l = jax.device_put(g.src, replicated)
-            dst_l = jax.device_put(g.dst, replicated)
-            if tropical:
-                w_l = jax.device_put(lanes, replicated)
-            m_local = g.m_pad
+            if boolean:
+                row_src, row_dst = jax.device_put(
+                    S.dst_rows(g.indptr_t, g.indices_t, n_real=g.n_nodes),
+                    replicated)
+            else:
+                src_l = jax.device_put(g.src, replicated)
+                dst_l = jax.device_put(g.dst, replicated)
+                if tropical:
+                    w_l = jax.device_put(lanes, replicated)
 
     deg = jnp.zeros(n_pad, jnp.float32).at[: g.n_nodes].set(
         jnp.asarray(g.out_degrees(), jnp.float32))
@@ -250,7 +275,16 @@ def prepare_sharded(g: CSRGraph, mesh: Mesh, *, weights=None,
     return ShardedOperands(graph=g, mesh=mesh, config=config, n_pad=n_pad,
                            n_shards=C, m_local=m_local, dense_op=dense_op,
                            src_l=src_l, dst_l=dst_l, w_l=w_l, w_min=w_min,
-                           deg=deg)
+                           row_src=row_src, row_dst=row_dst, deg=deg)
+
+
+def _partition_rows(src: np.ndarray, dst: np.ndarray, n: int):
+    """Destination rows of one lane partition (global ids, sentinel
+    ``n``), padded like every partition to the same row count."""
+    real = dst < n
+    part = CSRGraph.from_edges(src[real], dst[real], n, dedup=False,
+                               remove_self_loops=False, pad_to=len(src))
+    return S.dst_rows(part.indptr_t, part.indices_t, n_real=n)
 
 
 # --------------------------------------------------------------------------
@@ -268,11 +302,13 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
     nk = n_pad // C
     all_axes = tuple(mesh.axis_names)
 
-    def run_local(dense_l, src_e, dst_e, w_e, w_min, deg_l, f0_l, dist0_l,
-                  sigma0_l, steps):
+    def run_local(dense_l, src_e, dst_e, w_e, row_src, row_dst, w_min,
+                  deg_l, f0_l, dist0_l, sigma0_l, steps):
         if src_e.ndim == 2:              # (1, e_pad) model-axis block row
             src_e, dst_e = src_e[0], dst_e[0]
             w_e = w_e[0] if w_e.ndim == 2 else w_e
+        if row_src.ndim == 3:            # (1, W, R) model-axis block
+            row_src, row_dst = row_src[0], row_dst[0]
         s_l = f0_l.shape[0]
         fused = fused_combine = None
         fused_steps_l = 0
@@ -379,10 +415,9 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
                 adj_pull_l = pack_bits(jnp.transpose(dense_l) != 0) \
                     if use_kernel else jnp.zeros((1, 1), jnp.uint32)
                 push = S.boolean_forms(
-                    dense_l, adj_pull_l,
-                    jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-                    n_pad=n_pad, s=s_l, bn=cfg.bn, bk=cfg.bk,
-                    use_kernel=use_kernel, interpret=interpret)[S.PUSH]
+                    dense_l, adj_pull_l, None, n_pad=n_pad, s=s_l,
+                    bn=cfg.bn, bk=cfg.bk, use_kernel=use_kernel,
+                    interpret=interpret)[S.PUSH]
                 if vertex_sharded:
                     def dense_form(f, d, p, step):
                         k0 = jax.lax.axis_index(MODEL_AXIS) * nk
@@ -418,7 +453,7 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
                                 (~stopped).astype(jnp.int32), all_axes)
                             return prod, alive == 0
 
-        # ---- sparse form: scatter-⊕ over the shard's CSR lanes --------
+        # ---- sparse form: scatter-⊕ over the shard's lanes (rows) ---
         sparse_form = None
         if cfg.need_sparse:
             if counting:
@@ -453,7 +488,7 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
             else:
                 sparse_c = S.boolean_forms(
                     jnp.zeros((1, 1), jnp.int8),
-                    jnp.zeros((1, 1), jnp.uint32), src_e, dst_e,
+                    jnp.zeros((1, 1), jnp.uint32), (row_src, row_dst),
                     n_pad=n_pad, s=s_l, use_kernel=False,
                     interpret=interpret)[S.SPARSE]
                 if vertex_sharded:
@@ -513,20 +548,26 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
     row_spec = P(dp, None) if dp else P(None, None)
     dense_spec = P(MODEL_AXIS, None) \
         if (vertex_sharded and cfg.need_dense) else P()
+    boolean = not (tropical or counting)
+    lanes_sharded = vertex_sharded and cfg.need_sparse
     lane_spec = P(MODEL_AXIS, None) \
-        if (vertex_sharded and cfg.need_sparse) else P()
+        if (lanes_sharded and not boolean) else P()
     w_spec = lane_spec if tropical else P()   # boolean w_l is a 1-D dummy
+    rows_sharded = lanes_sharded and boolean
+    row_src_spec = P(MODEL_AXIS, None, None) if rows_sharded else P()
+    row_dst_spec = P(MODEL_AXIS, None) if rows_sharded else P()
 
     sharded = jax.shard_map(
         run_local, mesh=mesh,
-        in_specs=(dense_spec, lane_spec, lane_spec, w_spec, P(), P(),
-                  row_spec, row_spec, row_spec, P()),
+        in_specs=(dense_spec, lane_spec, lane_spec, w_spec, row_src_spec,
+                  row_dst_spec, P(), P(), row_spec, row_spec, row_spec,
+                  P()),
         out_specs=(row_spec, row_spec, P(), P(), P()),
         check_vma=False)
 
     @jax.jit
-    def runner(dense_op, src_l, dst_l, w_l, w_min, deg, sources, n_valid,
-               steps):
+    def runner(dense_op, src_l, dst_l, w_l, row_src, row_dst, w_min, deg,
+               sources, n_valid, steps):
         s_pad = sources.shape[0]
         f0 = one_hot_frontier(sources, n_pad, dtype=jnp.int8)
         row_ok = (jnp.arange(s_pad) < n_valid)[:, None]
@@ -545,8 +586,8 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
         else:
             # inert row-sharded dummy so the shard_map arity stays fixed
             sigma0 = jnp.zeros((s_pad, 1), jnp.float32)
-        return sharded(dense_op, src_l, dst_l, w_l, w_min, deg, f0, dist0,
-                       sigma0, steps)
+        return sharded(dense_op, src_l, dst_l, w_l, row_src, row_dst, w_min,
+                       deg, f0, dist0, sigma0, steps)
 
     return runner
 
@@ -605,7 +646,8 @@ def sharded_apsp(g: Union[CSRGraph, ShardedOperands],
     runner = _make_runner(ops.mesh, cfg, ops.n_pad, n, ops.m_local,
                           use_kernel, interpret, ops.n_shards)
     dist, sigma, step, dir_counts, edges = runner(
-        ops.dense_op, ops.src_l, ops.dst_l, ops.w_l, ops.w_min, ops.deg,
+        ops.dense_op, ops.src_l, ops.dst_l, ops.w_l, ops.row_src,
+        ops.row_dst, ops.w_min, ops.deg,
         jnp.asarray(padded), jnp.int32(len(srcs)),
         jnp.int32(cfg.max_sweeps or n))
     return ShardedApspResult(dist=dist[: len(srcs), :n], sweeps=step,

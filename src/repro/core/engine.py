@@ -134,6 +134,7 @@ class PreparedGraph:
     _adj: Optional[jax.Array] = dataclasses.field(default=None, repr=False)
     _adj_pull: Optional[jax.Array] = dataclasses.field(default=None,
                                                        repr=False)
+    _rows: Optional[tuple] = dataclasses.field(default=None, repr=False)
 
     @property
     def adj(self) -> jax.Array:
@@ -150,6 +151,16 @@ class PreparedGraph:
             self._adj_pull = self.graph.to_pull_packed(self.n_pad,
                                                        adj=self._adj)
         return self._adj_pull
+
+    @property
+    def rows(self) -> Tuple[jax.Array, jax.Array]:
+        """The sparse form's ``(row_src, row_dst)`` destination-row layout
+        (:func:`repro.core.sweep.dst_rows`)."""
+        if self._rows is None:
+            g = self.graph
+            self._rows = S.dst_rows(g.indptr_t, g.indices_t,
+                                    n_real=g.n_nodes)
+        return self._rows
 
 
 def prepare_graph(g, *, align: int = 128) -> PreparedGraph:
@@ -221,18 +232,20 @@ def choose_direction(stats: SweepStats, *, n_pad: int, s: int, m_pad: int,
 # --------------------------------------------------------------------------
 
 @functools.partial(jax.jit,
-                   static_argnames=("cfg", "n_real", "n_pad", "max_steps",
-                                    "use_kernel", "interpret",
+                   static_argnames=("cfg", "n_real", "n_pad", "m_pad",
+                                    "max_steps", "use_kernel", "interpret",
                                     "forced_dir", "fused_steps"))
-def _run_batch(adj, adj_pull, src_idx, dst_idx, deg, sources, n_valid, *,
-               cfg: EngineConfig, n_real: int, n_pad: int, max_steps: int,
-               use_kernel: bool, interpret: bool,
+def _run_batch(adj, adj_pull, row_src, row_dst, deg, sources, n_valid, *,
+               cfg: EngineConfig, n_real: int, n_pad: int, m_pad: int,
+               max_steps: int, use_kernel: bool, interpret: bool,
                forced_dir: Optional[int],
                fused_steps: int = 0) -> SweepState:
     # n_valid is traced (not static): the serving loop flushes micro-batches
-    # of whatever size is pending, and each distinct count must not retrace
+    # of whatever size is pending, and each distinct count must not retrace.
+    # row_src/row_dst: the sparse form's destination rows
+    # (PreparedGraph.rows); the cost model prices the sparse form by the
+    # CSR's m_pad
     s = sources.shape[0]
-    m_pad = src_idx.shape[0]
     bs = min(s, 128)
 
     with obs.scope("batch.init"):
@@ -247,7 +260,7 @@ def _run_batch(adj, adj_pull, src_idx, dst_idx, deg, sources, n_valid, *,
         dist0 = jnp.where(row_ok & (jnp.arange(n_pad)[None, :] < n_real),
                           dist0, 0)
 
-    forms = S.boolean_forms(adj, adj_pull, src_idx, dst_idx, n_pad=n_pad,
+    forms = S.boolean_forms(adj, adj_pull, (row_src, row_dst), n_pad=n_pad,
                             s=s, bn=cfg.bn, bk=cfg.bk,
                             pull_chunk=cfg.pull_chunk,
                             use_kernel=use_kernel, interpret=interpret)
@@ -299,8 +312,8 @@ def measure_sweep_costs(pg: "PreparedGraph", s: int, cfg: EngineConfig, *,
     f[:, ::17] = 1
     dist = np.full((s, n_pad), int(UNREACHED), np.int32)
     dist[:, ::4] = 1
-    forms = S.boolean_forms(pg.adj, pg.adj_pull, pg.graph.src, pg.graph.dst,
-                            n_pad=n_pad, s=s, bn=cfg.bn, bk=cfg.bk,
+    forms = S.boolean_forms(pg.adj, pg.adj_pull, pg.rows, n_pad=n_pad,
+                            s=s, bn=cfg.bn, bk=cfg.bk,
                             pull_chunk=cfg.pull_chunk, use_kernel=use_kernel,
                             interpret=interpret)
     result = S.time_sweep_forms(forms, jnp.asarray(f), jnp.asarray(dist))
@@ -348,12 +361,12 @@ def apsp_engine_blocks(
 ) -> Iterator[Tuple[np.ndarray, jax.Array, SweepState]]:
     """Stream (source_ids, dist_rows, raw_sweep_state) one source tile at a
     time — the non-materializing form for large n."""
-    with obs.span("engine.plan"):
+    with obs.span("engine.plan") as plan_span:
         pg = g if isinstance(g, PreparedGraph) else prepare_graph(g)
+        graph = pg.graph
         # TuningPlan overlay (no-op without one): tiles clamped to this
         # graph's padding, fused gate, cost constants
         config = autotune.apply(config, semiring="boolean", n_pad=pg.n_pad)
-        graph = pg.graph
         n = graph.n_nodes
         srcs = np.arange(n, dtype=np.int32) if sources is None else \
             np.asarray(sources, np.int32)
@@ -392,20 +405,29 @@ def apsp_engine_blocks(
             forced_dir in (None, PULL)
             or (forced_dir in (None, PUSH) and use_kernel)) else \
             jnp.zeros((1, 1), jnp.uint32)
+        if forced_dir in (None, SPARSE):
+            row_src, row_dst = pg.rows
+            plan_span.set_metadata(
+                sparse_layout="rows", rows=row_dst.shape[0],
+                lane_fill=graph.n_edges / row_src.size)
+        else:
+            row_src, row_dst = jnp.zeros((1, 1), jnp.int32), \
+                jnp.zeros((1,), jnp.int32)
+            plan_span.set_metadata(sparse_layout="none")
     for lo in range(0, len(srcs), B):
         block = srcs[lo: lo + B]
         valid = len(block)
         with obs.span("engine.tile", valid=valid, tile=B):
             padded = np.zeros(B, np.int32)
             padded[:valid] = block
-            st = _run_batch(adj, adj_pull, pg.graph.src, pg.graph.dst,
-                            pg.deg, jnp.asarray(padded), jnp.int32(valid),
+            st = _run_batch(adj, adj_pull, row_src, row_dst, pg.deg,
+                            jnp.asarray(padded), jnp.int32(valid),
                             cfg=config, n_real=n, n_pad=pg.n_pad,
-                            max_steps=max_steps,
+                            m_pad=graph.m_pad, max_steps=max_steps,
                             use_kernel=use_kernel, interpret=interpret,
                             forced_dir=forced_dir, fused_steps=fused_steps)
-            rows = st.dist[:valid, :n]
-        yield block, rows, st
+            dist = st.dist[:valid, :n]
+        yield block, dist, st
 
 
 def apsp_engine(g: Union[CSRGraph, PreparedGraph],

@@ -15,8 +15,10 @@ False and ``dist[n]`` is pinned 0 (visited), so padding is inert without
 masks.
 
 This module is the boolean-semiring SPARSE instantiation of the shared
-sweep layer (core/sweep.py): ``sovm_sssp`` pins the sparse form — with
-in-loop parent tracking — into the one ``sweep_loop`` driver.  Work
+sweep layer (core/sweep.py): ``sovm_sssp`` pins the sparse form — on
+the graph's destination rows (``sweep.dst_rows``: the lanes above grouped
+by ``dst``, one scatter update per row of lanes), with in-loop parent
+tracking — into the one ``sweep_loop`` driver.  Work
 accounting: the true SOVM work per sweep is sum(out_degree[frontier])
 (Eq. 10 → total = E_wcc(i)); the driver tracks it exactly in
 ``edges_touched`` so the complexity claims are empirically checkable even
@@ -57,10 +59,33 @@ def sovm_sweep(g: CSRGraph, frontier: jax.Array, dist: jax.Array):
     return new, pcand
 
 
-@partial(jax.jit, static_argnames=("max_steps",))
-def sovm_sssp(g: CSRGraph, source, *,
+def sovm_sssp(g: CSRGraph, source, *, rows=None,
               max_steps: Optional[int] = None) -> SovmState:
-    """DAWN-SOVM single-source shortest paths.  O(E_wcc(i)) useful work."""
+    """DAWN-SOVM single-source shortest paths.  O(E_wcc(i)) useful work.
+
+    ``rows`` is ``g``'s destination-row layout (:func:`sweep.dst_rows`,
+    cached as ``PreparedGraph.rows``); pass it when searching one graph
+    more than once, else each call builds it."""
+    return _sovm_sssp(g, _rows_of(g, rows), source, max_steps=max_steps)
+
+
+def sovm_msbfs(g: CSRGraph, sources: jax.Array, *, rows=None,
+               max_steps: Optional[int] = None) -> SovmState:
+    """Multi-source SOVM via vmap over sources (S small) — the sparse-graph
+    analogue of bovm_msbfs.  For large S on dense graphs prefer the BOVM
+    matmul path.  ``rows`` as for :func:`sovm_sssp`."""
+    return _sovm_msbfs(g, _rows_of(g, rows), jnp.asarray(sources, jnp.int32),
+                       max_steps=max_steps)
+
+
+def _rows_of(g: CSRGraph, rows):
+    return S.dst_rows(g.indptr_t, g.indices_t, n_real=g.n_nodes) \
+        if rows is None else rows
+
+
+@partial(jax.jit, static_argnames=("max_steps",))
+def _sovm_sssp(g: CSRGraph, rows, source, *,
+               max_steps: Optional[int] = None) -> SovmState:
     n = g.n_nodes
     max_steps = n if max_steps is None else max_steps
     src = jnp.asarray(source, jnp.int32)
@@ -72,8 +97,8 @@ def sovm_sssp(g: CSRGraph, source, *,
                            jnp.zeros(1, jnp.float32)])
 
     _, _, sparse = S.boolean_forms(
-        jnp.zeros((1, 1), jnp.int8), jnp.zeros((1, 1), jnp.uint32),
-        g.src, g.dst, n_pad=n + 1, s=1, track_parent=True)
+        jnp.zeros((1, 1), jnp.int8), jnp.zeros((1, 1), jnp.uint32), rows,
+        n_pad=n + 1, s=1, track_parent=True)
 
     st = S.sweep_loop((sparse,), S.make_state(frontier0, dist0, parent0,
                                               n_forms=1),
@@ -84,13 +109,10 @@ def sovm_sssp(g: CSRGraph, source, *,
 
 
 @partial(jax.jit, static_argnames=("max_steps",))
-def sovm_msbfs(g: CSRGraph, sources: jax.Array, *,
-               max_steps: Optional[int] = None) -> SovmState:
-    """Multi-source SOVM via vmap over sources (S small) — the sparse-graph
-    analogue of bovm_msbfs.  For large S on dense graphs prefer the BOVM
-    matmul path."""
-    run = jax.vmap(lambda s: sovm_sssp(g, s, max_steps=max_steps))
-    return run(jnp.asarray(sources, jnp.int32))
+def _sovm_msbfs(g: CSRGraph, rows, sources: jax.Array, *,
+                max_steps: Optional[int] = None) -> SovmState:
+    return jax.vmap(lambda s: _sovm_sssp(g, rows, s,
+                                         max_steps=max_steps))(sources)
 
 
 def reconstruct_path(parent, source: int, target: int, max_len: int):

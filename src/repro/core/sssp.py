@@ -78,7 +78,8 @@ def sssp(g: Union[CSRGraph, PreparedGraph], source: int, *,
                           derive_parents(graph, st.dist)[0] if parents
                           else None)
     assert method == "sovm", method
-    st = sovm_sssp(graph, source)   # parent tracked in-loop (free)
+    st = sovm_sssp(graph, source,    # parent tracked in-loop (free)
+                   rows=g.rows if isinstance(g, PreparedGraph) else None)
     return SsspResult(st.dist, st.sweeps, st.edges_touched, st.parent)
 
 
@@ -96,7 +97,8 @@ def multi_source(g: Union[CSRGraph, PreparedGraph],
                           derive_parents(graph, st.dist) if parents
                           else None)
     assert method == "sovm", method
-    st = sovm_msbfs(graph, jnp.asarray(srcs))   # parent tracked in-loop
+    st = sovm_msbfs(graph, jnp.asarray(srcs),   # parent tracked in-loop
+                    rows=g.rows if isinstance(g, PreparedGraph) else None)
     return SsspResult(st.dist, jnp.max(st.sweeps),
                       jnp.sum(st.edges_touched), st.parent)
 

@@ -30,8 +30,14 @@ Spans (arguments in brackets):
                           [semiring, n_sources]
   dawn.engine.plan        the engine's set-up before its first tile:
                           tuning overlay, kernel/direction/fused
-                          resolution, operand selection (a lazy dense or
-                          packed operand build lands here)
+                          resolution, operand selection (a lazy dense,
+                          packed or row operand build lands here)
+                          [sparse_layout: ``rows`` where the sparse
+                          form can run (its destination rows,
+                          ``sweep.dst_rows``), ``none`` where a pinned
+                          push or pull leaves it out; with ``rows``:
+                          rows, the layout's R, and lane_fill, real
+                          lanes over R x W]
   dawn.engine.tile        one source tile: padding, upload, the batch
                           program's dispatch, the result slice
                           [valid: live rows, tile: rows of the program]

@@ -8,7 +8,9 @@ from repro.core import (EngineConfig, apsp_engine, bfs_queue_numpy,
                         choose_direction, frontier_stats,
                         measure_sweep_costs, prepare_graph, sweep_costs,
                         PUSH, PULL, SPARSE, UNREACHED)
+from repro.core import sweep as S
 from repro.graph import generators as gen
+from repro.graph.csr import CSRGraph
 
 
 def _ref_dists(g, sources):
@@ -155,6 +157,101 @@ def test_degree_stats_and_padding():
     # sentinel must index a dead column: n_padded > n_nodes always
     assert g.n_padded() >= g.n_nodes + 1
     assert g.n_padded() % 128 == 0
+
+
+# -- the sparse form's destination-row layout -------------------------------
+
+def _kronecker(seed=0, pad_to=None):
+    """Undirected Graph500 Kronecker graph, scale 10, edge factor 16."""
+    g = gen.rmat(10, 16, seed=seed, directed=False)
+    if pad_to is None:
+        return g
+    src, dst = g.edge_arrays_np()
+    return CSRGraph.from_edges(src, dst, g.n_nodes, dedup=False,
+                               remove_self_loops=False, pad_to=pad_to)
+
+
+ROW_LAYOUT_GRAPHS = {
+    "kronecker": _kronecker,
+    "directed": lambda: gen.erdos_renyi(300, 20.0, seed=3),
+    "grid": lambda: gen.grid2d(64, 64),
+}
+
+
+@pytest.mark.parametrize("graph", sorted(ROW_LAYOUT_GRAPHS))
+def test_dst_rows_hold_every_lane_once(graph):
+    g = ROW_LAYOUT_GRAPHS[graph]()
+    n = g.n_nodes
+    width = S.row_width(g.m_pad, n)
+    assert width == (1 if graph == "grid" else S.ROW_WIDTH)
+    slots, row_dst = (np.asarray(a) for a in S.dst_rows(
+        g.indptr_t, g.indices_t, n_real=n))
+    assert slots.shape == (width, S.row_count(g.m_pad, n))
+    row_src = slots.T                                     # (R, W)
+    assert row_dst.shape == row_src.shape[:1]
+    assert np.all(np.diff(row_dst) >= 0)                  # ascending
+    assert np.all(row_src[row_dst == n] == n)             # pad rows
+    real = row_src != n
+    got = sorted(zip(row_src[real], np.broadcast_to(
+        row_dst[:, None], row_src.shape)[real]))
+    src, dst = g.edge_arrays_np()
+    assert got == sorted(zip(src, dst))                   # each lane once
+    # each destination's lanes fill its rows from the front, in CSC order
+    for v in np.unique(dst)[:50]:
+        mine = row_src[row_dst == v].ravel()
+        deg = int(np.sum(dst == v))
+        np.testing.assert_array_equal(
+            mine[:deg], np.asarray(g.indices_t)[
+                int(g.indptr_t[v]):int(g.indptr_t[v + 1])])
+        assert np.all(mine[deg:] == n)
+        assert len(mine) == -(-deg // width) * width
+
+
+def test_dst_rows_shape_depends_on_m_pad_and_n_only():
+    a, b = _kronecker(0, pad_to=21504), _kronecker(1, pad_to=21504)
+    assert a.n_edges != b.n_edges
+    la = S.dst_rows(a.indptr_t, a.indices_t, n_real=a.n_nodes)
+    lb = S.dst_rows(b.indptr_t, b.indices_t, n_real=b.n_nodes)
+    assert [x.shape for x in la] == [x.shape for x in lb]
+    assert la[0].shape[1] % 128 == 0
+
+
+def test_low_degree_grid_runs_on_rows():
+    """A 64x64 grid (in-degree 2-4) takes rows of one lane each, in
+    destination order, and the engine's sparse sweeps search it
+    exactly."""
+    grid = gen.grid2d(64, 64)
+    row_src, row_dst = prepare_graph(grid).rows
+    assert row_src.shape == (1, S.row_count(grid.m_pad, grid.n_nodes))
+    assert int(np.sum(np.asarray(row_dst) < grid.n_nodes)) == grid.n_edges
+    sources = np.arange(0, 4096, 97, dtype=np.int32)
+    res = apsp_engine(grid, sources,
+                      config=EngineConfig(mode="sparse", source_batch=16))
+    np.testing.assert_array_equal(np.asarray(res.dist),
+                                  _ref_dists(grid, sources))
+    assert int(res.direction_counts[SPARSE]) == int(
+        res.direction_counts.sum()) > 0
+
+
+def test_auto_on_rows_matches_lanes(monkeypatch):
+    """mode=auto's per-sweep choice prices the sparse form by m_pad, so
+    the row width changes neither the distances nor the choices: rows of
+    8 against rows of one lane each (the lane form in CSC order)."""
+    g = _kronecker()
+    sources = np.arange(40, dtype=np.int32)
+    cfg = EngineConfig(source_batch=16, dynamic=True)
+    rows = apsp_engine(g, sources, config=cfg)
+    monkeypatch.setattr(S, "dst_rows", lambda *a, n_real: S._dst_rows(
+        *a, n_real=n_real, width=1))
+    assert prepare_graph(g).rows[0].shape[0] == 1
+    lanes = apsp_engine(g, sources, config=cfg)
+    np.testing.assert_array_equal(np.asarray(rows.dist),
+                                  np.asarray(lanes.dist))
+    np.testing.assert_array_equal(np.asarray(rows.direction_counts),
+                                  np.asarray(lanes.direction_counts))
+    assert int(rows.direction_counts[SPARSE]) > 0
+    np.testing.assert_array_equal(np.asarray(rows.dist),
+                                  _ref_dists(g, sources))
 
 
 def test_to_pull_packed_roundtrip():

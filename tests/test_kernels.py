@@ -384,10 +384,9 @@ def test_fused_counting_multisweep_matches_per_sweep():
 def _boolean_push_jaxpr(n=256, s=64):
     import repro.core.sweep as S
     adj_pull = jnp.zeros((n, n // 32), jnp.uint32)
-    push = S.boolean_forms(jnp.zeros((1, 1), jnp.int8), adj_pull,
-                           jnp.zeros((1,), jnp.int32),
-                           jnp.zeros((1,), jnp.int32), n_pad=n, s=s,
-                           use_kernel=True, interpret=True)[S.PUSH]
+    push = S.boolean_forms(jnp.zeros((1, 1), jnp.int8), adj_pull, None,
+                           n_pad=n, s=s, use_kernel=True,
+                           interpret=True)[S.PUSH]
     f = jnp.zeros((s, n), jnp.int8)
     d = jnp.zeros((s, n), jnp.int32)
     p = jnp.zeros((s, n), jnp.int32)
@@ -418,10 +417,9 @@ def test_no_f32_dot_guard_sees_nested_jaxprs():
     import repro.core.sweep as S
     n, s = 256, 64
     adj = jnp.zeros((n, n), jnp.int8)
-    ref_push = S.boolean_forms(adj, jnp.zeros((1, 1), jnp.uint32),
-                               jnp.zeros((1,), jnp.int32),
-                               jnp.zeros((1,), jnp.int32), n_pad=n, s=s,
-                               use_kernel=False, interpret=True)[S.PUSH]
+    ref_push = S.boolean_forms(adj, jnp.zeros((1, 1), jnp.uint32), None,
+                               n_pad=n, s=s, use_kernel=False,
+                               interpret=True)[S.PUSH]
     f = jnp.zeros((s, n), jnp.int8)
     d = jnp.zeros((s, n), jnp.int32)
     p = jnp.zeros((s, n), jnp.int32)

@@ -34,9 +34,10 @@ def batch_op_names(g, forced_dir):
     adj = pg.adj if dense else jnp.zeros((1, 1), jnp.int8)
     adj_pull = pg.adj_pull if dense else jnp.zeros((1, 1), jnp.uint32)
     text = E._run_batch.lower(
-        adj, adj_pull, pg.graph.src, pg.graph.dst, pg.deg,
+        adj, adj_pull, *pg.rows, pg.deg,
         jnp.arange(8, dtype=jnp.int32), jnp.int32(8), cfg=cfg,
-        n_real=g.n_nodes, n_pad=pg.n_pad, max_steps=g.n_nodes,
+        n_real=g.n_nodes, n_pad=pg.n_pad, m_pad=g.m_pad,
+        max_steps=g.n_nodes,
         use_kernel=False, interpret=True,
         forced_dir=forced_dir).compile().as_text()
     return set(SCOPE_NAMES.findall(text))
@@ -84,7 +85,7 @@ def test_form_names(name, want):
 def test_every_builder_form_has_a_known_name(graph):
     pg = E.prepare_graph(graph)
     w = jnp.ones(graph.m_pad, jnp.float32)
-    forms = (*S.boolean_forms(pg.adj, pg.adj_pull, graph.src, graph.dst,
+    forms = (*S.boolean_forms(pg.adj, pg.adj_pull, pg.rows,
                               n_pad=pg.n_pad, s=8),
              *S.tropical_forms(None, graph.src, graph.dst, w)[1:],
              S.minlabel_form(graph.src, graph.dst),
@@ -170,3 +171,23 @@ def test_serving_spans_nest(graph, tmp_path):
                                      "dawn.serve.flush.copy",
                                      "dawn.serve.flush.fill"]
     assert all(inside(e, flush) for e in parts)
+
+
+@pytest.mark.parametrize("kind", ["rows", "none"])
+def test_plan_span_carries_the_sparse_layout(kind, tmp_path):
+    g = gen.rmat(10, 16, seed=0, directed=False)
+    h = dawn.prepare(g, source_batch=8, use_kernel=False,
+                     mode="sparse" if kind == "rows" else "push")
+    jax.block_until_ready(h.apsp(np.arange(8)))        # compile outside
+    events = profile(tmp_path, lambda: jax.block_until_ready(
+        h.apsp(np.arange(8))))
+    plan, = [e for e in events if e[0] == "dawn.engine.plan"]
+    assert plan[3]["sparse_layout"] == kind
+    if kind == "rows":
+        rows = S.row_count(g.m_pad, g.n_nodes)
+        assert plan[3]["rows"] == rows
+        assert plan[3]["lane_fill"] == pytest.approx(
+            g.n_edges / (rows * S.ROW_WIDTH))
+        assert 0.5 < plan[3]["lane_fill"] < 1
+    else:
+        assert "rows" not in plan[3] and "lane_fill" not in plan[3]

@@ -9,11 +9,13 @@ import pytest
 import jax.numpy as jnp
 
 import repro.core as core
-from repro.core import (EngineConfig, WeightedConfig, apsp_engine,
-                        derive_parents, minplus_sssp, multi_source,
-                        prepare_weighted, reconstruct_path, sovm_sssp,
-                        sssp, weighted_apsp)
+from repro.core import (EngineConfig, UNREACHED, WeightedConfig,
+                        apsp_engine, derive_parents, minplus_sssp,
+                        multi_source, prepare_graph, prepare_weighted,
+                        reconstruct_path, sovm_sssp, sssp, weighted_apsp)
+from repro.core import sweep as S
 from repro.graph import generators as gen
+from repro.graph.csr import CSRGraph
 
 from oracles import bfs_dist, bfs_dists, dijkstra_dists
 
@@ -205,12 +207,99 @@ def test_multi_source_auto_parent_roundtrip():
         _check_paths(g, res.dist[i], parent[i], int(s))
 
 
+def test_sovm_on_destination_rows():
+    """Single-source SOVM on destination rows: exact distances, valid
+    paths, the post-pass's parents, and the same search from a layout
+    built once and passed in."""
+    g = gen.rmat(10, 16, seed=4, directed=False)
+    st = sovm_sssp(g, 5)
+    np.testing.assert_array_equal(np.asarray(st.dist), bfs_dist(g, 5))
+    _check_paths(g, st.dist, st.parent, 5)
+    post = np.asarray(derive_parents(g, st.dist[None, :]))[0]
+    np.testing.assert_array_equal(post, np.asarray(st.parent))
+    again = sovm_sssp(g, 5, rows=prepare_graph(g).rows)
+    for a, b in zip(st, again):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_derive_parents_matches_inloop_sovm():
     """Post-pass parents == in-loop sparse tracking (same tie-break)."""
     g = gen.erdos_renyi(120, 4.0, directed=False, seed=23)
     st = sovm_sssp(g, 0)
     post = np.asarray(derive_parents(g, st.dist[None, :]))[0]
     np.testing.assert_array_equal(post, np.asarray(st.parent))
+
+
+def _mixed_directed_graph():
+    """A directed graph whose CSC differs from its CSR, with a hub of
+    in-degree 30 (> 3W for W = 8), vertices of in-degree exactly 4 and
+    8, isolated vertices 45..47, and lanes padded well past m."""
+    rng = np.random.default_rng(5)
+    src = [*range(1, 31), *range(10, 14), *range(20, 28)]
+    dst = [0] * 30 + [1] * 4 + [2] * 8
+    src += list(rng.integers(3, 45, 90))
+    dst += list(rng.integers(3, 45, 90))
+    return CSRGraph.from_edges(np.array(src), np.array(dst), 48,
+                                    pad_to=384)
+
+
+ROW_GRAPHS = {
+    "directed_mixed": _mixed_directed_graph,
+    "kronecker": lambda: gen.rmat(8, 8, seed=3, directed=False),
+}
+
+
+def _lane_form(g):
+    """The oracle: the sparse form on CSR lanes, one scatter update per
+    lane at the unsorted destinations, parents by max active source."""
+    def sparse(f, d, p, step):
+        active = f[..., g.src] != 0
+        hits = jnp.zeros(d.shape, jnp.bool_).at[..., g.dst].max(active)
+        new = hits & (d == UNREACHED)
+        pcand = jnp.full(d.shape, -1, jnp.int32).at[..., g.dst].max(
+            jnp.where(active, g.src, -1))
+        return (new.astype(jnp.int8), jnp.where(new, step, d),
+                jnp.where(new, pcand, p))
+    return sparse
+
+
+@pytest.mark.parametrize("width", [4, 8])
+@pytest.mark.parametrize("s", [1, 64, 128])
+@pytest.mark.parametrize("graph", sorted(ROW_GRAPHS))
+def test_row_form_matches_lane_form(graph, s, width):
+    """The sparse form on destination rows gives the lane form's new
+    frontier, distances and parents (max source id wins), one sweep from
+    a random mid-search state and whole searches from sources."""
+    g = ROW_GRAPHS[graph]()
+    n, n_pad = g.n_nodes, g.n_padded()
+    rows = S._dst_rows(g.indptr_t, g.indices_t, n_real=n, width=width)
+    forms = {"lanes": _lane_form(g), "rows": S.boolean_forms(
+        None, None, rows, n_pad=n_pad, s=s, track_parent=True)[S.SPARSE]}
+
+    rng = np.random.default_rng(s + width)
+    f = (rng.random((s, n_pad)) < 0.2).astype(np.int8)
+    d = np.where(rng.random((s, n_pad)) < 0.6, -1, 1).astype(np.int32)
+    f[:, n:], d[:, n:] = 0, 0
+    p = np.full((s, n_pad), -1, np.int32)
+    lane_out = forms["lanes"](f, d, p, jnp.int32(2))
+    row_out = forms["rows"](f, d, p, jnp.int32(2))
+    for a, b in zip(lane_out, row_out):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    sources = rng.integers(0, n, s)
+    f0 = np.zeros((s, n_pad), np.int8)
+    f0[np.arange(s), sources] = 1
+    d0 = np.where(f0 != 0, 0, -1).astype(np.int32)
+    d0[:, n:] = 0
+    runs = [S.sweep_loop(
+        (forms[layout],), S.make_state(f0, d0, p, n_forms=1),
+        max_steps=n) for layout in ("lanes", "rows")]
+    np.testing.assert_array_equal(np.asarray(runs[0].dist),
+                                  np.asarray(runs[1].dist))
+    np.testing.assert_array_equal(np.asarray(runs[0].parent),
+                                  np.asarray(runs[1].parent))
+    np.testing.assert_array_equal(np.asarray(runs[1].dist)[:, :n],
+                                  bfs_dists(g, sources))
 
 
 def test_derive_parents_weighted(random_weighted):

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.engine import EngineConfig, _run_batch
-from repro.core.sweep import SPARSE
+from repro.core.sweep import SPARSE, row_count, row_width
 from repro.kernels import common
 from repro.kernels.bovm import (fused_boolean_multisweep, packed_pull_sweep,
                                 packed_push_sweep)
@@ -136,21 +136,24 @@ def test_fused_counting_multisweep_compiles(one_chip):
 
 def test_graph500_scale20_sparse_batch_fits_one_chip(one_chip):
     """The whole ``_run_batch`` program of ``prepare(g, mode="sparse",
-    source_batch=64).apsp(keys)`` at Graph500 scale 20: the edge-lane
-    gather/scatter state must fit the chip's HBM."""
+    source_batch=64).apsp(keys)`` at Graph500 scale 20, on destination
+    rows: the gather/scatter state must fit the chip's HBM."""
     n_pad = -(-(SCALE20_N + 1) // 128) * 128
     cfg = EngineConfig(mode="sparse", source_batch=SCALE20_KEYS)
+    r = row_count(SCALE20_LANES, SCALE20_N)
+    w = row_width(SCALE20_LANES, SCALE20_N)
+    assert w == 8
     compiled = _run_batch.lower(
         _spec(one_chip, (1, 1), jnp.int8),
         _spec(one_chip, (1, 1), jnp.uint32),
-        _spec(one_chip, (SCALE20_LANES,), jnp.int32),
-        _spec(one_chip, (SCALE20_LANES,), jnp.int32),
+        _spec(one_chip, (w, r), jnp.int32),
+        _spec(one_chip, (r,), jnp.int32),
         _spec(one_chip, (n_pad,), jnp.float32),
         _spec(one_chip, (SCALE20_KEYS,), jnp.int32),
         _spec(one_chip, (), jnp.int32),
-        cfg=cfg, n_real=SCALE20_N, n_pad=n_pad, max_steps=SCALE20_N,
-        use_kernel=True, interpret=False, forced_dir=SPARSE,
-        fused_steps=0).compile()
+        cfg=cfg, n_real=SCALE20_N, n_pad=n_pad, m_pad=SCALE20_LANES,
+        max_steps=SCALE20_N, use_kernel=True, interpret=False,
+        forced_dir=SPARSE, fused_steps=0).compile()
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
