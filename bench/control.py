@@ -6,11 +6,11 @@ program as configured or for the control.
 The control is the program's own option that breaks the configuration's
 guarantee of exact distances: ``max_steps`` caps the sweeps a search may
 run, so vertices farther than the cap stay unreached.  Each seed runs
-the cell's driver as ``bench.run`` does (its own graph, warm-up, a window
-of ``--seconds`` at the cell's own load, the comparison) and prints one
-JSON line with the compared numbers and ``correct``.  The limits in
-``PERF.md`` were set from these readings; the benchmark's runs do not
-call this.
+the cell's driver as ``bench.run`` does (its own traffic on the
+configuration's one graph, warm-up, a window of ``--seconds`` at the
+cell's own load, the comparison) and prints one JSON line with the
+compared numbers and ``correct``.  The limits in ``PERF.md`` were set
+from these readings; the benchmark's runs do not call this.
 """
 from __future__ import annotations
 

@@ -1,14 +1,17 @@
 """Closed loop of batched searches: ``repro.prepare(g, ...).apsp(keys)``
 back to back, ``batch`` keys per call.
 
-The keys are the vertices of degree >= 1 in an order drawn from the seed,
-taken ``batch`` at a time, so no key repeats until all have been used.
+The graph is the configuration's (its ``graph_seed``), and so are the
+job's batches: its vertices of degree >= 1 in an order drawn from the
+``graph_seed``, cut ``batch`` at a time (the last batch filled from the
+start).  ``--seed`` orders the batches, so every seed runs the same
+batches, each once a pass, in an order of its own.
 The window runs whole calls until ``--seconds`` has passed; TEPS is the
 Graph500 count (each key's component edges, counted once) over the time
 from the first call's start to the last call's end.
 
 Correct means: every row reaches exactly the vertices of its key's
-component, and ``check_rows`` rows drawn from the seed equal the plain
+component, and ``check_rows`` rows drawn from ``--seed`` equal the plain
 BFS, entry for entry.
 """
 from __future__ import annotations
@@ -34,11 +37,12 @@ def _row(dist, i):
     return jax.lax.dynamic_index_in_dim(dist, i, keepdims=False)
 
 
-def _keys(order: np.ndarray, call: int, batch: int) -> np.ndarray:
-    """The keys of the ``call``-th call: the next ``batch`` of the order,
-    wrapping round at its end."""
-    lo = call * batch
-    return order[np.arange(lo, lo + batch) % len(order)].astype(np.int32)
+def batches(keys: np.ndarray, batch: int) -> np.ndarray:
+    """(n, ``batch``) int32: ``keys`` cut into batches in their order,
+    the last filled from the start."""
+    n = -(-len(keys) // batch)
+    return keys[np.arange(n * batch) % len(keys)].reshape(
+        n, batch).astype(np.int32)
 
 
 def run(ctx: Context) -> Outcome:
@@ -46,13 +50,15 @@ def run(ctx: Context) -> Outcome:
     g = ctx.graph()
     h = repro.prepare(g, **ctx.facade_options())
     deg = np.diff(np.asarray(g.indptr))
-    order = ctx.rng(STREAM_KEYS).permutation(np.flatnonzero(deg > 0))
+    job = batches(ctx.job_rng(STREAM_KEYS).permutation(
+        np.flatnonzero(deg > 0)), batch)
+    order = ctx.rng(STREAM_KEYS).permutation(len(job))
     rng_check = ctx.rng(STREAM_CHECK)
 
     # warm-up: the window's shapes, on keys of degree 0 where there are
     # enough (one sweep), else on live keys
     isolated = np.flatnonzero(deg == 0)
-    warm = isolated[:batch] if len(isolated) >= batch else order[:batch]
+    warm = isolated[:batch] if len(isolated) >= batch else job[0]
     res = jax.block_until_ready(h.apsp(warm.astype(np.int32)))
     jax.block_until_ready((_reached(res.dist), _row(res.dist, jnp.int32(0))))
     del res
@@ -62,7 +68,7 @@ def run(ctx: Context) -> Outcome:
     with ctx.window():
         t0 = time.perf_counter()
         while True:
-            k = _keys(order, len(keys), batch)
+            k = job[order[len(keys) % len(order)]]
             with ctx.spans.span("apsp"):
                 res = jax.block_until_ready(h.apsp(k))
             t1 = time.perf_counter()

@@ -1,15 +1,19 @@
 """Open loop of point-to-point distance queries against
 ``repro.prepare(g, ...).serve(...)``.
 
-Arrivals are Poisson at the traffic file's ``rate`` (queries per second)
-over the window.  Each query's source is drawn Zipf(``zipf_s``) over an
-order of the degree >= 1 vertices drawn from the seed, its target
-uniformly among them.  One thread runs the loop: submit every query that
-is due (the row cache and the landmark oracle answer at submit), then
+The graph is the configuration's (its ``graph_seed``); the queries are
+drawn from ``--seed``.  Arrivals are Poisson at the traffic file's
+``rate`` (queries per second) over the window, conditioned on their
+count: every seed offers ``rate`` x seconds queries, at times of its
+own.  Each query's source is drawn Zipf(``zipf_s``) over an order of
+the degree >= 1 vertices drawn from the seed, its target uniformly
+among them.  One thread runs the loop: submit every query that is due
+(the row cache and the landmark oracle answer at submit), then
 ``tick()`` (one sweep flush when a query waits), then
 ``drain_completed()``.  Each query is timed from its due time to its
-answer.  After the window closes no query is submitted, and the loop runs
-on until every query due in it has its answer, for at most ``drain_s``.
+answer.  After the window closes no query is submitted, and the loop
+runs on until every query due in it has its answer, for at most
+``drain_s``.
 
 Correct means: every query due in the window is answered, and every
 answer to a query from a checked source equals the plain BFS.  The
@@ -52,10 +56,11 @@ class Served:
 
 def schedule(rng: np.random.Generator, order: np.ndarray, rate: float,
              seconds: float, zipf_s: float):
-    """(arrival offsets, sources, targets) of one window."""
-    gaps = rng.exponential(1.0 / rate, size=int(rate * seconds * 2) + 64)
-    at = np.cumsum(gaps)
-    at = at[at < seconds]
+    """(arrival offsets, sources, targets) of one window.  Given their
+    count, the arrival times of a Poisson process are independent and
+    uniform over the window; the count is fixed, so the seed changes
+    when queries come and not how many."""
+    at = np.sort(rng.uniform(0.0, seconds, size=round(rate * seconds)))
     w = 1.0 / np.arange(1, len(order) + 1) ** zipf_s
     src = order[rng.choice(len(order), size=len(at), p=w / w.sum())]
     dst = order[rng.integers(len(order), size=len(at))]

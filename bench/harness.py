@@ -24,7 +24,8 @@ import numpy as np
 from bench import trace as trace_mod
 from bench.instrument import CompileClock, Spans
 
-# numpy streams drawn from one --seed: each use has its own
+# numpy streams, one for each use: ``Context.rng`` draws them from the
+# run's --seed, ``Context.job_rng`` from the configuration's graph_seed
 STREAM_KEYS, STREAM_WARM, STREAM_CHECK, STREAM_ARRIVALS = 1, 2, 3, 4
 
 
@@ -54,13 +55,32 @@ class Context:
     compiles_in_window: Optional[int] = None
     reduced: Optional[trace_mod.Trace] = None
 
+    @property
+    def graph_seed(self) -> int:
+        """The configuration's fixed seed: a deployment serves one graph,
+        and ``--seed`` varies only the traffic on it."""
+        seed = self.config.get("graph_seed")
+        if type(seed) is not int or seed < 0:
+            raise ValueError(
+                f"configuration {self.config['name']!r} needs a "
+                f"graph_seed, a whole number >= 0; it has {seed!r}")
+        return seed
+
     def rng(self, stream: int) -> np.random.Generator:
+        """The traffic's numpy stream ``stream`` of the run's ``--seed``."""
         return np.random.default_rng([self.seed, stream])
 
+    def job_rng(self, stream: int) -> np.random.Generator:
+        """Numpy stream ``stream`` of the configuration's ``graph_seed``:
+        what the deployment fixes beside its graph, such as the batches
+        of a job."""
+        return np.random.default_rng([self.graph_seed, stream])
+
     def graph(self):
+        """The configuration's one graph, built from its ``graph_seed``."""
         gen = importlib.import_module(
             f"bench.generators.{self.config['generator']}")
-        g = gen.build(self.seed, self.config["graph"])
+        g = gen.build(self.graph_seed, self.config["graph"])
         print(f"graph: {g.n_nodes} vertices, {g.n_edges} lanes, m_pad "
               f"{g.m_pad}", file=sys.stderr)
         return g

@@ -2,8 +2,9 @@
 
     python3 -m bench.knee --workload kron_s15_p2p --seed 7 --rates 50,100,200 --seconds 10
 
-One process sets the cell up once, then runs one window per rate, in the
-order given, each with its own arrivals.  Per rate it prints one JSON
+One process sets the cell up once, on the configuration's one graph (its
+``graph_seed``), then runs one window per rate, in the order given, each
+with its own arrivals drawn from ``--seed``.  Per rate it prints one JSON
 line: the queries due, the backlog (due but unanswered) at each quarter
 of the window, the latency median and 95th percentile, and the median
 flush time.  The knee is the highest rate whose backlog does not grow

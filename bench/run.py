@@ -8,9 +8,11 @@ names; its traffic in ``bench/traffic/<traffic>.json``, whose ``driver``
 names ``bench/drivers/<driver>.py``; each per-layer metric in
 ``bench/metrics/<metric>.py``; the device's peaks in ``bench/peaks.json``.
 
-The run builds its graph from ``--seed``, warms up (that is ``setup_s``),
-measures for ``--seconds``, checks what the window produced against the
-plain reference, and prints one JSON line last on standard output.  With
+The run builds the configuration's one graph from the ``graph_seed``
+that the configuration fixes, draws its traffic (keys, arrivals, the rows
+it checks) from ``--seed``, warms up (that is ``setup_s``), measures for
+``--seconds``, checks what the window produced against the plain
+reference, and prints one JSON line last on standard output.  With
 ``--trace 1`` it traces a shorter window and reports the per-layer
 metrics in place of the end-to-end ones.  It exits with 2, printing no
 result, when JAX finds no TPU or fewer chips than the cell asks for.

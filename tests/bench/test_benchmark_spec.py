@@ -29,6 +29,9 @@ def test_configuration_files(conf):
     assert any(conf["file"].startswith(p + "/") for p in SPEC["paths"])
     data = json.loads(path.read_text())
     assert data["name"] == conf["name"]
+    # one graph per configuration: --seed draws only the traffic on it
+    seed = data["graph_seed"]
+    assert type(seed) is int and seed >= 0
     assert set(conf["reduced"]) <= set(data["reduced"])
     assert importlib.util.find_spec(f"bench.generators.{data['generator']}")
     assert any(w["config"] == conf["name"] for w in SPEC["workloads"])

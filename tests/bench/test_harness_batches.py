@@ -1,7 +1,9 @@
 """Whole runs of the batch cells at a test's size: sound, under each
 fault the cell can have, and as the control."""
+import numpy as np
 import pytest
 
+from bench.drivers.closed_batches import batches
 from harness_faults import FAULTS, plant, run
 
 CELLS = ["g500_s20_bfs64", "kron_s15_apsp"]
@@ -32,3 +34,12 @@ def test_control_is_not_correct(cell):
     # the control: sweeps capped below the depth the graph needs
     line = run(cell, options={"max_steps": 2})
     assert not line["correct"], line["checks"]
+
+
+def test_a_job_is_its_keys_cut_into_batches():
+    keys = np.arange(100, 200)
+    job = batches(keys, 16)
+    assert job.shape == (7, 16) and job.dtype == np.int32
+    np.testing.assert_array_equal(job.ravel()[:100], keys)
+    np.testing.assert_array_equal(job[-1, 4:], keys[:12])
+    assert batches(keys, 20).shape == (5, 20)

@@ -96,6 +96,8 @@ def test_malformed_traces_are_refused():
     ("flush_ms.p2p", {"flush_s": [0.1, 0.3]}, 200.0),
     ("flush_ms.p2p", {"flush_s": []}, None),
     ("gen_lag_p95_ms.p2p", {"lag_s": [0.001] * 19 + [1.0]}, 50.95),
+    ("sweeps_per_call.bfs", {"sweeps": [7, 8, 7, 7]}, 7.25),
+    ("sweeps_per_call.bfs", {"sweeps": []}, None),
 ])
 def test_counter_readers(name, counters, want):
     got = reader(name)(made_up(), counters)
