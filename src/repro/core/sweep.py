@@ -355,6 +355,36 @@ def _dst_rows(indptr_t, indices_t, *, n_real: int, width: int):
     return row_src, row_dst
 
 
+# Widest vertex axis one row scatter writes.  On a v5e, XLA lays a
+# (64, n) bool scatter target out key-major from n = 2^21 on and takes a
+# slow scatter path there, with a copy on each side: 1.79 s a sweep at
+# Graph500 scale 21 against 40 ms at scale 20.  Pieces of at most 1.5 x
+# 2^20 vertices keep the vertex-major layout and the fast path.
+SCATTER_COLUMNS = 3 << 19
+
+
+def row_scatter_or(shape, row_dst, rows_or) -> jax.Array:
+    """Bool ``shape`` (..., n): each row's OR, ``rows_or`` (..., R), ORed
+    into its destination at the sorted ``row_dst`` (R,) — in pieces of
+    the vertex axis of at most :data:`SCATTER_COLUMNS`.  A piece takes
+    every row: rows outside it are masked off and clipped to its edge,
+    which keeps the indices sorted."""
+    n = shape[-1]
+    pieces = -(-n // SCATTER_COLUMNS)
+    if pieces == 1:
+        return jnp.zeros(shape, jnp.bool_).at[..., row_dst].max(
+            rows_or, indices_are_sorted=True)
+    width = -(-n // pieces // 128) * 128
+    parts = []
+    for lo in range(0, n, width):
+        w = min(width, n - lo)
+        inside = (row_dst >= lo) & (row_dst < lo + w)
+        parts.append(jnp.zeros(shape[:-1] + (w,), jnp.bool_).at[
+            ..., jnp.clip(row_dst - lo, 0, w - 1)].max(
+            rows_or & inside, indices_are_sorted=True))
+    return jnp.concatenate(parts, axis=-1)
+
+
 def _pull_chunk_size(n_pad: int, preferred: int) -> int:
     for c in (preferred, 512, 256, 128):
         if c <= n_pad and n_pad % c == 0:
@@ -442,8 +472,7 @@ def boolean_forms(adj, adj_pull, rows, *, n_pad: int, s: int,
         # compared before the gather: one pass over (S, n), not over
         # (S, W, R) (on a v5e 10% of the sweep at 2^15 vertices)
         active = (f != 0)[..., row_src]                  # (..., W, R)
-        hits = jnp.zeros(d.shape, jnp.bool_).at[..., row_dst].max(
-            jnp.any(active, axis=-2), indices_are_sorted=True)
+        hits = row_scatter_or(d.shape, row_dst, jnp.any(active, axis=-2))
         new = hits & (d == UNREACHED)
         if track_parent:
             pcand = jnp.full(d.shape, -1, jnp.int32).at[..., row_dst].max(

@@ -37,7 +37,11 @@ Spans (arguments in brackets):
                           ``sweep.dst_rows``), ``none`` where a pinned
                           push or pull leaves it out; with ``rows``:
                           rows, the layout's R, and lane_fill, real
-                          lanes over R x W]
+                          lanes over R x W; always table_bytes, the
+                          (tile, n_pad) frontier table the sparse form
+                          gathers from, and operand_bytes, the operands
+                          the plan resolved, dummies included: both
+                          from shapes alone]
   dawn.engine.tile        one source tile: padding, upload, the batch
                           program's dispatch, the result slice
                           [valid: live rows, tile: rows of the program]

@@ -173,21 +173,40 @@ def test_serving_spans_nest(graph, tmp_path):
     assert all(inside(e, flush) for e in parts)
 
 
-@pytest.mark.parametrize("kind", ["rows", "none"])
-def test_plan_span_carries_the_sparse_layout(kind, tmp_path):
-    g = gen.rmat(10, 16, seed=0, directed=False)
-    h = dawn.prepare(g, source_batch=8, use_kernel=False,
-                     mode="sparse" if kind == "rows" else "push")
+def plan_stats(g, mode, tmp_path):
+    """The ``dawn.engine.plan`` span's args of an 8-source ``apsp``."""
+    h = dawn.prepare(g, source_batch=8, use_kernel=False, mode=mode)
     jax.block_until_ready(h.apsp(np.arange(8)))        # compile outside
     events = profile(tmp_path, lambda: jax.block_until_ready(
         h.apsp(np.arange(8))))
     plan, = [e for e in events if e[0] == "dawn.engine.plan"]
-    assert plan[3]["sparse_layout"] == kind
+    return plan[3]
+
+
+@pytest.mark.parametrize("kind", ["rows", "none"])
+def test_plan_span_carries_the_sparse_layout(kind, tmp_path):
+    g = gen.rmat(10, 16, seed=0, directed=False)
+    stats = plan_stats(g, "sparse" if kind == "rows" else "push", tmp_path)
+    assert stats["sparse_layout"] == kind
     if kind == "rows":
         rows = S.row_count(g.m_pad, g.n_nodes)
-        assert plan[3]["rows"] == rows
-        assert plan[3]["lane_fill"] == pytest.approx(
+        assert stats["rows"] == rows
+        assert stats["lane_fill"] == pytest.approx(
             g.n_edges / (rows * S.ROW_WIDTH))
-        assert 0.5 < plan[3]["lane_fill"] < 1
+        assert 0.5 < stats["lane_fill"] < 1
     else:
-        assert "rows" not in plan[3] and "lane_fill" not in plan[3]
+        assert "rows" not in stats and "lane_fill" not in stats
+
+
+@pytest.mark.parametrize("mode", ["sparse", "push"])
+def test_plan_span_carries_table_and_operand_bytes(mode, tmp_path):
+    g = gen.rmat(10, 16, seed=0, directed=False)
+    stats = plan_stats(g, mode, tmp_path)
+    n_pad = E.prepare_graph(g).n_pad
+    assert stats["table_bytes"] == n_pad * 8
+    if mode == "sparse":       # (1, 1) int8 and uint32 dense dummies
+        rows = S.row_count(g.m_pad, g.n_nodes)
+        operands = 1 + 4 + (S.ROW_WIDTH + 1) * rows * 4
+    else:                      # the int8 adjacency; pull and row dummies
+        operands = n_pad * n_pad + 4 + 4 + 4
+    assert stats["operand_bytes"] == operands

@@ -302,6 +302,35 @@ def test_row_form_matches_lane_form(graph, s, width):
                                   bfs_dists(g, sources))
 
 
+@pytest.mark.parametrize("columns", [128, 256])
+def test_row_scatter_in_pieces_matches_lane_form(columns, monkeypatch):
+    """A vertex axis wider than ``SCATTER_COLUMNS`` is scattered in
+    pieces (scale 21 on a v5e); the new frontier and distances stay the
+    lane form's, and the pieces alone equal one scatter."""
+    monkeypatch.setattr(S, "SCATTER_COLUMNS", columns)
+    g = ROW_GRAPHS["kronecker"]()
+    n, n_pad = g.n_nodes, g.n_padded()
+    assert n_pad > columns
+    rows = S.dst_rows(g.indptr_t, g.indices_t, n_real=n)
+    sparse = S.boolean_forms(None, None, rows, n_pad=n_pad,
+                             s=64)[S.SPARSE]
+    rng = np.random.default_rng(columns)
+    f = (rng.random((64, n_pad)) < 0.2).astype(np.int8)
+    d = np.where(rng.random((64, n_pad)) < 0.6, -1, 1).astype(np.int32)
+    f[:, n:], d[:, n:] = 0, 0
+    p = np.full((64, n_pad), -1, np.int32)
+    for a, b in zip(_lane_form(g)(f, d, p, jnp.int32(2))[:2],
+                    sparse(f, d, p, jnp.int32(2))[:2]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    row_dst = rows[1]
+    rows_or = rng.random((3, row_dst.shape[0])) < 0.3
+    want = np.zeros((3, n_pad), bool)
+    np.logical_or.at(want, (slice(None), np.asarray(row_dst)), rows_or)
+    np.testing.assert_array_equal(
+        np.asarray(S.row_scatter_or((3, n_pad), row_dst, rows_or)), want)
+
+
 def test_derive_parents_weighted(random_weighted):
     g, w = random_weighted(70, 3.0, 29)
     res = weighted_apsp(g, w, np.arange(8),

@@ -5,8 +5,8 @@ primitives that Mosaic refuses: a dynamic slice along lanes, a block
 whose last dim is neither a multiple of 128 nor the whole width, a
 scalar stored to VMEM, more scoped VMEM than the kernel may use.  These
 tests compile each kernel the TPU path dispatches, at the widths
-``chip_smoke.py`` runs, plus the Graph500 scale-20 sparse batch program,
-whose device memory must fit one 16 GB chip.  Nothing runs; a compile
+``chip_smoke.py`` runs, plus the Graph500 sparse batch program at scales
+20 and 21, whose device memory must fit one 16 GB chip.  Nothing runs; a compile
 that passes here is not a chip run.
 
 The topology is described inside a fixture, never at import time: only
@@ -39,6 +39,7 @@ S = 128                    # sources per tile in the kernel phase
 SCALE20_N = 1 << 20
 SCALE20_LANES = 1 << 25
 SCALE20_KEYS = 64
+SCALE21_LANES = 61 << 20   # bench/configs/graph500_s21.json's m_pad
 V5E_HBM_BYTES = 15.75 * 2 ** 30      # what the v5e compiler may allocate
 
 
@@ -134,14 +135,14 @@ def test_fused_counting_multisweep_compiles(one_chip):
              bs=128, max_sweeps=n, interpret=False)
 
 
-def test_graph500_scale20_sparse_batch_fits_one_chip(one_chip):
-    """The whole ``_run_batch`` program of ``prepare(g, mode="sparse",
-    source_batch=64).apsp(keys)`` at Graph500 scale 20, on destination
-    rows: the gather/scatter state must fit the chip's HBM."""
-    n_pad = -(-(SCALE20_N + 1) // 128) * 128
+def _sparse_batch_bytes(one_chip, n, m_pad):
+    """Device bytes of the whole ``_run_batch`` program of
+    ``prepare(g, mode="sparse", source_batch=64).apsp(keys)`` over
+    ``n`` vertices and ``m_pad`` lanes, on destination rows of 8."""
+    n_pad = -(-(n + 1) // 128) * 128
     cfg = EngineConfig(mode="sparse", source_batch=SCALE20_KEYS)
-    r = row_count(SCALE20_LANES, SCALE20_N)
-    w = row_width(SCALE20_LANES, SCALE20_N)
+    r = row_count(m_pad, n)
+    w = row_width(m_pad, n)
     assert w == 8
     compiled = _run_batch.lower(
         _spec(one_chip, (1, 1), jnp.int8),
@@ -151,10 +152,24 @@ def test_graph500_scale20_sparse_batch_fits_one_chip(one_chip):
         _spec(one_chip, (n_pad,), jnp.float32),
         _spec(one_chip, (SCALE20_KEYS,), jnp.int32),
         _spec(one_chip, (), jnp.int32),
-        cfg=cfg, n_real=SCALE20_N, n_pad=n_pad, m_pad=SCALE20_LANES,
-        max_steps=SCALE20_N, use_kernel=True, interpret=False,
+        cfg=cfg, n_real=n, n_pad=n_pad, m_pad=m_pad,
+        max_steps=n, use_kernel=True, interpret=False,
         forced_dir=SPARSE, fused_steps=0).compile()
     mem = compiled.memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def test_graph500_scale20_sparse_batch_fits_one_chip(one_chip):
+    """The batch program at Graph500 scale 20, on destination rows: the
+    gather/scatter state must fit the chip's HBM."""
+    used = _sparse_batch_bytes(one_chip, SCALE20_N, SCALE20_LANES)
     assert 0 < used <= V5E_HBM_BYTES, used / 2 ** 30
+
+
+def test_graph500_scale21_sparse_batch_fits_one_chip(one_chip):
+    """The same program at scale 21 with the benchmark configuration's
+    ``m_pad`` (61 x 2^20 lanes), the largest Graph500 graph one chip
+    holds: a temporary as large as the row gather's would not fit."""
+    used = _sparse_batch_bytes(one_chip, 2 * SCALE20_N, SCALE21_LANES)
+    assert 12 * 2 ** 30 < used <= V5E_HBM_BYTES, used / 2 ** 30
