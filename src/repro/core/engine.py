@@ -414,10 +414,14 @@ def apsp_engine_blocks(
             row_src, row_dst = jnp.zeros((1, 1), jnp.int32), \
                 jnp.zeros((1,), jnp.int32)
             plan_span.set_metadata(sparse_layout="none")
-        # from shapes alone: the (B, n_pad) int8 frontier table the sparse
-        # form gathers from, and the operands this plan resolved
+        # from shapes alone: the (B, n_pad) int8 frontier table, the words
+        # a vertex and the bytes of the table the sparse form gathers from
+        # (packed past sweep.PACK_GATHER_BYTES), and the operands this
+        # plan resolved
         plan_span.set_metadata(
             table_bytes=pg.n_pad * B,
+            gather_words=S.gather_words((B, pg.n_pad)),
+            gather_table_bytes=S.gather_table_bytes((B, pg.n_pad)),
             operand_bytes=sum(x.nbytes for x in (adj, adj_pull, row_src,
                                                  row_dst)))
     for lo in range(0, len(srcs), B):

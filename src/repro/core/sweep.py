@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 from typing import Any, Callable, NamedTuple, Optional, Sequence, Tuple
 
@@ -50,7 +51,7 @@ import jax.numpy as jnp
 from .. import obs
 from ..kernels import common as kernel_common
 from ..kernels import registry as kernel_registry
-from .frontier import UNREACHED, pack_bits
+from .frontier import UNREACHED, WORD, pack_bits
 
 PUSH, PULL, SPARSE = 0, 1, 2
 DIRECTION_NAMES = ("push", "pull", "sparse")
@@ -367,8 +368,11 @@ def row_scatter_or(shape, row_dst, rows_or) -> jax.Array:
     """Bool ``shape`` (..., n): each row's OR, ``rows_or`` (..., R), ORed
     into its destination at the sorted ``row_dst`` (R,) — in pieces of
     the vertex axis of at most :data:`SCATTER_COLUMNS`.  A piece takes
-    every row: rows outside it are masked off and clipped to its edge,
-    which keeps the indices sorted."""
+    every row: rows before it land in a margin of 128 columns on its
+    left, rows after it in one on its right, and both margins are cut
+    off.  That keeps the indices sorted and ``rows_or`` unmasked: XLA
+    fuses a mask on it into the relayout of the packed gather's rows
+    (:func:`packed_row_or`), once for each piece."""
     n = shape[-1]
     pieces = -(-n // SCATTER_COLUMNS)
     if pieces == 1:
@@ -378,11 +382,60 @@ def row_scatter_or(shape, row_dst, rows_or) -> jax.Array:
     parts = []
     for lo in range(0, n, width):
         w = min(width, n - lo)
-        inside = (row_dst >= lo) & (row_dst < lo + w)
-        parts.append(jnp.zeros(shape[:-1] + (w,), jnp.bool_).at[
-            ..., jnp.clip(row_dst - lo, 0, w - 1)].max(
-            rows_or & inside, indices_are_sorted=True))
+        idx = jnp.clip(row_dst - lo + 128, 127, w + 128)
+        parts.append(jnp.zeros(shape[:-1] + (w + 256,), jnp.bool_).at[
+            ..., idx].max(rows_or, indices_are_sorted=True)[..., 128:w + 128])
     return jnp.concatenate(parts, axis=-1)
+
+
+# Largest byte frontier table, keys x n_pad, that the sparse form gathers
+# from as it is.  On a v5e XLA keeps kron_s15's 4.2 MB table in VMEM (a
+# gathered slot ~1.8 ns) but Graph500 scale 20's 64 MiB one in HBM (11.3
+# ns); past this size the form gathers from the frontier packed 32 keys a
+# uint32 word, a table 8 times smaller (8 MiB at scale 20), which XLA
+# places in VMEM.
+PACK_GATHER_BYTES = 16 << 20
+
+
+def gather_words(shape) -> int:
+    """uint32 words a vertex that the sparse form gathers for a frontier
+    of ``shape`` (keys, n): ceil(keys / 32) where its byte table passes
+    :data:`PACK_GATHER_BYTES`, else 0 (it gathers the bytes).  1-D
+    single-source state has no key axis and is never packed."""
+    if len(shape) != 2 or shape[0] * shape[1] <= PACK_GATHER_BYTES:
+        return 0
+    return -(-shape[0] // WORD)
+
+
+def gather_table_bytes(shape) -> int:
+    """Bytes of the table the sparse form gathers from (see
+    :func:`gather_words`)."""
+    words = gather_words(shape)
+    return 4 * words * shape[-1] if words else math.prod(shape)
+
+
+def packed_row_or(f, row_src, words: int) -> jax.Array:
+    """Each destination row's OR of the (keys, n) frontier ``f``, as
+    (keys, R) bool, gathered from ``f`` packed to (words, n) uint32 words
+    (key 32w + b at bit b of word w; the keys padded with zeros to a
+    whole word).  The vertex axis stays minor, so at 64 keys the table
+    is ``u32[2, n]``; the pack goes through bytes, 8 keys an octet, so
+    that XLA's one relayout copy of the key axis is of bytes, not of
+    words (a v5e lays the (keys, n) state out key-minor)."""
+    keys, n = f.shape
+    bits = jnp.pad((f != 0).astype(jnp.uint8),
+                   ((0, words * WORD - keys), (0, 0)))
+    shifts = jnp.arange(8, dtype=jnp.uint8)[:, None]
+    octets = jnp.sum(bits.reshape(words * 4, 8, n) << shifts, axis=1,
+                     dtype=jnp.uint8)                    # (4 words, n)
+    shifts = 8 * jnp.arange(4, dtype=jnp.uint32)[:, None]
+    table = jnp.sum(octets.reshape(words, 4, n).astype(jnp.uint32) << shifts,
+                    axis=1, dtype=jnp.uint32)            # (words, n)
+    rows = jax.lax.reduce(table[:, row_src], jnp.uint32(0),
+                          jax.lax.bitwise_or, (1,))      # (words, R)
+    shifts = jnp.arange(WORD, dtype=jnp.uint32)[:, None]
+    bits = (rows[:, None, :] >> shifts) & jnp.uint32(1)  # (words, 32, R)
+    return bits.reshape(words * WORD, -1)[:keys] != 0
 
 
 def _pull_chunk_size(n_pad: int, preferred: int) -> int:
@@ -469,10 +522,16 @@ def boolean_forms(adj, adj_pull, rows, *, n_pad: int, s: int,
     def sparse(f, d, p, step):
         # batched SOVM sweep (paper Alg. 2 / Eq. 9 union as scatter-OR)
         row_src, row_dst = rows
-        # compared before the gather: one pass over (S, n), not over
-        # (S, W, R) (on a v5e 10% of the sweep at 2^15 vertices)
-        active = (f != 0)[..., row_src]                  # (..., W, R)
-        hits = row_scatter_or(d.shape, row_dst, jnp.any(active, axis=-2))
+        # the parent tree needs each slot's activity: it gathers bytes
+        words = 0 if track_parent else gather_words(f.shape)
+        if words:
+            rows_or = packed_row_or(f, row_src, words)
+        else:
+            # compared before the gather: one pass over (S, n), not over
+            # (S, W, R) (on a v5e 10% of the sweep at 2^15 vertices)
+            active = (f != 0)[..., row_src]              # (..., W, R)
+            rows_or = jnp.any(active, axis=-2)
+        hits = row_scatter_or(d.shape, row_dst, rows_or)
         new = hits & (d == UNREACHED)
         if track_parent:
             pcand = jnp.full(d.shape, -1, jnp.int32).at[..., row_dst].max(

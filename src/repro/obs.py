@@ -38,10 +38,15 @@ Spans (arguments in brackets):
                           push or pull leaves it out; with ``rows``:
                           rows, the layout's R, and lane_fill, real
                           lanes over R x W; always table_bytes, the
-                          (tile, n_pad) frontier table the sparse form
-                          gathers from, and operand_bytes, the operands
-                          the plan resolved, dummies included: both
-                          from shapes alone]
+                          (tile, n_pad) int8 frontier table,
+                          gather_words, the uint32 words a vertex the
+                          sparse form gathers from the frontier packed
+                          32 keys a word (0 where it gathers the bytes,
+                          under ``sweep.PACK_GATHER_BYTES``),
+                          gather_table_bytes, the table it gathers
+                          from, and operand_bytes, the operands the
+                          plan resolved, dummies included: all from
+                          shapes alone]
   dawn.engine.tile        one source tile: padding, upload, the batch
                           program's dispatch, the result slice
                           [valid: live rows, tile: rows of the program]

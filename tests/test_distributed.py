@@ -57,6 +57,37 @@ def test_sharded_apsp_boolean_bit_identical_to_single_device():
     assert "OK" in out
 
 
+def test_sharded_sparse_form_packs_its_local_table():
+    """The sharded executor's boolean sparse form applies
+    ``sweep.PACK_GATHER_BYTES`` to its local shapes: with the limit at 0
+    each shard gathers its 32 keys packed to one word, on a source-only
+    and a source x vertex mesh, and the distances stay the plain BFS's."""
+    out = _run("""
+        import sys; sys.path.insert(0, "tests")
+        import numpy as np
+        from oracles import bfs_dists
+        from repro.graph import generators as gen
+        from repro.core import ShardedConfig, sharded_apsp
+        from repro.core import sweep as S
+        from repro.launch.mesh import make_mesh
+        S.PACK_GATHER_BYTES = 0
+        words = []
+        packed = S.packed_row_or
+        S.packed_row_or = lambda f, row_src, w: (
+            words.append((f.shape, w)) or packed(f, row_src, w))
+        g = gen.rmat(8, 8, directed=False, seed=3)
+        sources = np.arange(64, dtype=np.int32)
+        for shape, axes in [((2,), ("data",)), ((2, 2), ("data", "model"))]:
+            res = sharded_apsp(g, sources, mesh=make_mesh(shape, axes),
+                               config=ShardedConfig(mode="sparse"))
+            np.testing.assert_array_equal(np.asarray(res.dist),
+                                          bfs_dists(g, sources))
+        assert words and all(s[0] == 32 and w == 1 for s, w in words), words
+        print("OK")
+    """, devices=4)
+    assert "OK" in out
+
+
 @pytest.mark.slow
 def test_sharded_apsp_tropical_bit_identical_to_single_device():
     """Same acceptance for the tropical semiring: (min,+) APSP sharded

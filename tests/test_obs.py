@@ -210,3 +210,16 @@ def test_plan_span_carries_table_and_operand_bytes(mode, tmp_path):
     else:                      # the int8 adjacency; pull and row dummies
         operands = n_pad * n_pad + 4 + 4 + 4
     assert stats["operand_bytes"] == operands
+    # 8 keys over n_pad: a byte table far below PACK_GATHER_BYTES
+    assert stats["gather_words"] == 0
+    assert stats["gather_table_bytes"] == n_pad * 8
+
+
+def test_plan_span_carries_the_packed_gather(tmp_path, monkeypatch):
+    """Past ``PACK_GATHER_BYTES`` the plan names the packed gather: one
+    uint32 word a vertex for 8 keys, a table of 4 bytes a vertex."""
+    monkeypatch.setattr(S, "PACK_GATHER_BYTES", 0)
+    g = gen.rmat(10, 16, seed=0, directed=False)
+    stats = plan_stats(g, "sparse", tmp_path)
+    assert stats["gather_words"] == 1
+    assert stats["gather_table_bytes"] == 4 * E.prepare_graph(g).n_pad

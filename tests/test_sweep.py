@@ -331,6 +331,101 @@ def test_row_scatter_in_pieces_matches_lane_form(columns, monkeypatch):
         np.asarray(S.row_scatter_or((3, n_pad), row_dst, rows_or)), want)
 
 
+def _mid_search_state(rng, s, n, n_pad):
+    f = (rng.random((s, n_pad)) < 0.2).astype(np.int8)
+    d = np.where(rng.random((s, n_pad)) < 0.6, -1, 1).astype(np.int32)
+    f[:, n:], d[:, n:] = 0, 0
+    return f, d, np.full((s, n_pad), -1, np.int32)
+
+
+@pytest.mark.parametrize("width", [1, 8])
+@pytest.mark.parametrize("s", [32, 64, 96, 128])
+@pytest.mark.parametrize("graph", sorted(ROW_GRAPHS))
+def test_packed_gather_matches_lane_and_row_forms(graph, s, width,
+                                                  monkeypatch):
+    """Past ``PACK_GATHER_BYTES`` (0 here, so every keyed table packs)
+    the sparse form gathers the frontier packed 32 keys a word (96 keys
+    pad to 3 words); its new frontier and distances are the lane form's
+    and the byte gather's, one sweep from a random mid-search state and
+    whole searches from sources."""
+    g = ROW_GRAPHS[graph]()
+    n, n_pad = g.n_nodes, g.n_padded()
+    rows = S._dst_rows(g.indptr_t, g.indices_t, n_real=n, width=width)
+    sparse = S.boolean_forms(None, None, rows, n_pad=n_pad,
+                             s=s)[S.SPARSE]
+    rng = np.random.default_rng(s + width)
+    f, d, p = _mid_search_state(rng, s, n, n_pad)
+    assert S.gather_words((s, n_pad)) == 0
+    bytes_out = sparse(f, d, p, jnp.int32(2))
+    monkeypatch.setattr(S, "PACK_GATHER_BYTES", 0)
+    assert S.gather_words((s, n_pad)) == -(-s // 32)
+    assert S.gather_table_bytes((s, n_pad)) == 4 * -(-s // 32) * n_pad
+    packed_out = sparse(f, d, p, jnp.int32(2))
+    lane_out = _lane_form(g)(f, d, p, jnp.int32(2))
+    for a, b, c in zip(lane_out[:2], bytes_out[:2], packed_out[:2]):
+        np.testing.assert_array_equal(np.asarray(b), np.asarray(a))
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(a))
+
+    sources = rng.integers(0, n, s)
+    f0 = np.zeros((s, n_pad), np.int8)
+    f0[np.arange(s), sources] = 1
+    d0 = np.where(f0 != 0, 0, -1).astype(np.int32)
+    d0[:, n:] = 0
+    runs = [S.sweep_loop((form,), S.make_state(f0, d0, p, n_forms=1),
+                         max_steps=n) for form in (_lane_form(g), sparse)]
+    np.testing.assert_array_equal(np.asarray(runs[1].dist),
+                                  np.asarray(runs[0].dist))
+    assert int(runs[1].step) == int(runs[0].step)
+    np.testing.assert_array_equal(np.asarray(runs[1].dist)[:, :n],
+                                  bfs_dists(g, sources))
+
+
+@pytest.mark.parametrize("state", ["single_source", "track_parent"])
+def test_packed_gather_leaves_1d_state_and_parents_unpacked(state,
+                                                            monkeypatch):
+    """1-D single-source state has no key axis, and the in-loop parent
+    tree needs each slot's activity: both gather bytes at any table size
+    and still give the lane form's frontier, distances and parents."""
+    monkeypatch.setattr(S, "PACK_GATHER_BYTES", 0)
+
+    def refuse(*args):
+        raise AssertionError("packed gather taken")
+    monkeypatch.setattr(S, "packed_row_or", refuse)
+    g = ROW_GRAPHS["kronecker"]()
+    n, n_pad = g.n_nodes, g.n_padded()
+    rows = S.dst_rows(g.indptr_t, g.indices_t, n_real=n)
+    s = 64
+    f, d, p = _mid_search_state(np.random.default_rng(7), s, n, n_pad)
+    if state == "single_source":
+        f, d, p, s = f[0], d[0], p[0], 1
+        assert S.gather_words(f.shape) == 0
+    sparse = S.boolean_forms(None, None, rows, n_pad=n_pad, s=s,
+                             track_parent=True)[S.SPARSE]
+    for a, b in zip(_lane_form(g)(f, d, p, jnp.int32(2)),
+                    sparse(f, d, p, jnp.int32(2))):
+        np.testing.assert_array_equal(np.asarray(b), np.asarray(a))
+    if state == "single_source":
+        st = sovm_sssp(g, 5, rows=rows)
+        np.testing.assert_array_equal(np.asarray(st.dist), bfs_dist(g, 5))
+
+
+def test_engine_batch_on_the_packed_gather(monkeypatch):
+    """The batch engine's sparse form past ``PACK_GATHER_BYTES``: 40
+    keys on two words, the same distances as the plain BFS."""
+    monkeypatch.setattr(S, "PACK_GATHER_BYTES", 0)
+    traced = []
+    packed = S.packed_row_or
+    monkeypatch.setattr(S, "packed_row_or", lambda f, row_src, words: (
+        traced.append(words) or packed(f, row_src, words)))
+    g = gen.rmat(9, 12, seed=5, directed=False)
+    sources = np.arange(0, 400, 10)
+    res = apsp_engine(g, sources, config=EngineConfig(mode="sparse",
+                                                      source_batch=40))
+    assert traced == [2]
+    np.testing.assert_array_equal(np.asarray(res.dist),
+                                  bfs_dists(g, sources))
+
+
 def test_derive_parents_weighted(random_weighted):
     g, w = random_weighted(70, 3.0, 29)
     res = weighted_apsp(g, w, np.arange(8),

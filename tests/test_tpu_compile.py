@@ -13,6 +13,8 @@ The topology is described inside a fixture, never at import time: only
 one process may load the TPU library, and pytest-xdist workers import
 every test file.
 """
+import re
+
 import pytest
 import jax
 import jax.numpy as jnp
@@ -135,12 +137,33 @@ def test_fused_counting_multisweep_compiles(one_chip):
              bs=128, max_sweeps=n, interpret=False)
 
 
-def _sparse_batch_bytes(one_chip, n, m_pad):
-    """Device bytes of the whole ``_run_batch`` program of
-    ``prepare(g, mode="sparse", source_batch=64).apsp(keys)`` over
-    ``n`` vertices and ``m_pad`` lanes, on destination rows of 8."""
+KRON15_N = 1 << 15         # bench/configs/kron_s15.json: 2^15 vertices,
+KRON15_LANES = 895744      # its m_pad, 128 keys a call
+KRON15_KEYS = 128
+
+
+@pytest.fixture(scope="module")
+def sparse_batch(one_chip):
+    """``(n, m_pad, keys) -> (device bytes, gather tables, row copies)``
+    of the whole ``_run_batch`` program of ``prepare(g, mode="sparse",
+    source_batch=keys).apsp(keys)`` over ``n`` vertices and ``m_pad``
+    lanes, on destination rows of 8, each shape compiled once.  A gather
+    table is the type and layout of the operand a gather reads its slots
+    from, ``S(1)`` in the layout where it sits in VMEM; row copies count
+    the copies (relayouts) of a ``pred[R, keys]`` array of row ORs."""
+    compiled = {}
+
+    def get(n, m_pad, keys):
+        if (n, m_pad, keys) not in compiled:
+            compiled[n, m_pad, keys] = _compile_sparse_batch(
+                one_chip, n, m_pad, keys)
+        return compiled[n, m_pad, keys]
+    return get
+
+
+def _compile_sparse_batch(one_chip, n, m_pad, keys):
     n_pad = -(-(n + 1) // 128) * 128
-    cfg = EngineConfig(mode="sparse", source_batch=SCALE20_KEYS)
+    cfg = EngineConfig(mode="sparse", source_batch=keys)
     r = row_count(m_pad, n)
     w = row_width(m_pad, n)
     assert w == 8
@@ -150,26 +173,61 @@ def _sparse_batch_bytes(one_chip, n, m_pad):
         _spec(one_chip, (w, r), jnp.int32),
         _spec(one_chip, (r,), jnp.int32),
         _spec(one_chip, (n_pad,), jnp.float32),
-        _spec(one_chip, (SCALE20_KEYS,), jnp.int32),
+        _spec(one_chip, (keys,), jnp.int32),
         _spec(one_chip, (), jnp.int32),
         cfg=cfg, n_real=n, n_pad=n_pad, m_pad=m_pad,
         max_steps=n, use_kernel=True, interpret=False,
         forced_dir=SPARSE, fused_steps=0).compile()
     mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    types = dict(re.findall(r"%(\S+) = (\S+) ", text))
+    tables = [types[name]
+              for name in re.findall(r" gather\(%([^,\s)]+)", text)]
+    row_copies = len(re.findall(rf"= pred\[{r},{keys}\]\S* copy\(", text))
     return (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes), tables, \
+        row_copies
 
 
-def test_graph500_scale20_sparse_batch_fits_one_chip(one_chip):
+def test_graph500_scale20_sparse_batch_fits_one_chip(sparse_batch):
     """The batch program at Graph500 scale 20, on destination rows: the
     gather/scatter state must fit the chip's HBM."""
-    used = _sparse_batch_bytes(one_chip, SCALE20_N, SCALE20_LANES)
+    used, _, _ = sparse_batch(SCALE20_N, SCALE20_LANES, SCALE20_KEYS)
     assert 0 < used <= V5E_HBM_BYTES, used / 2 ** 30
 
 
-def test_graph500_scale21_sparse_batch_fits_one_chip(one_chip):
+def test_graph500_scale21_sparse_batch_fits_one_chip(sparse_batch):
     """The same program at scale 21 with the benchmark configuration's
     ``m_pad`` (61 x 2^20 lanes), the largest Graph500 graph one chip
-    holds: a temporary as large as the row gather's would not fit."""
-    used = _sparse_batch_bytes(one_chip, 2 * SCALE20_N, SCALE21_LANES)
-    assert 12 * 2 ** 30 < used <= V5E_HBM_BYTES, used / 2 ** 30
+    holds.  The packed gather's slots take 8 bytes, not a byte key padded
+    to 128 lanes: 5.97 GiB by this compile (13.6 GiB with the byte
+    gather)."""
+    used, _, _ = sparse_batch(2 * SCALE20_N, SCALE21_LANES, SCALE20_KEYS)
+    assert 0 < used <= 6.5 * 2 ** 30, used / 2 ** 30
+
+
+@pytest.mark.parametrize("n, m_pad", [(SCALE20_N, SCALE20_LANES),
+                                      (2 * SCALE20_N, SCALE21_LANES)])
+def test_graph500_sparse_batch_gathers_a_packed_vmem_table(sparse_batch, n,
+                                                           m_pad):
+    """At scales 20 and 21 the 64 keys' byte table (64 and 128 MiB) is
+    past ``PACK_GATHER_BYTES``: the sparse form gathers from the frontier
+    packed to two uint32 words a vertex, which XLA places in VMEM.  The
+    unpacked row ORs are relaid out for the scatter once, also where it
+    runs in two pieces (scale 21)."""
+    n_pad = -(-(n + 1) // 128) * 128
+    _, tables, row_copies = sparse_batch(n, m_pad, SCALE20_KEYS)
+    assert len(tables) == 1, tables
+    assert tables[0].startswith(f"u32[2,{n_pad}]{{"), tables
+    assert "S(1)" in tables[0], tables
+    assert row_copies == 1, row_copies
+
+
+def test_kron_s15_sparse_batch_still_gathers_bytes(sparse_batch):
+    """kron_s15's 128-key byte table (4.2 MB) is under
+    ``PACK_GATHER_BYTES`` and already in VMEM: it gathers ``pred`` as
+    before packing existed."""
+    _, tables, _ = sparse_batch(KRON15_N, KRON15_LANES, KRON15_KEYS)
+    assert len(tables) == 1, tables
+    assert tables[0].startswith("pred[128,32896]{"), tables
+    assert "S(1)" in tables[0], tables
