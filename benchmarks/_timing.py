@@ -22,7 +22,7 @@ def time_interleaved_stats(fns: Dict[str, Callable], repeats: int
     regression gate compares run-over-run (robust to a single slow
     round)."""
     for fn in fns.values():
-        fn()  # warmup: jit compile + calibration cache + device transfer
+        fn()  # warmup: jit compile + device transfer
     samples: Dict[str, list] = {k: [] for k in fns}
     for _ in range(repeats):
         for k, fn in fns.items():

@@ -2,10 +2,9 @@
 
 Runs one MXU-aligned source tile through the core/engine.py driver on each
 generator family three times — with the sweep direction pinned to push,
-pinned to pull, and chosen by the engine (calibrated per graph on the CPU
-reference path; per-sweep occupancy switching on the TPU kernel path) —
-and emits a JSON document with per-family timings plus the two acceptance
-booleans:
+pinned to pull, and chosen per sweep by the engine's occupancy cost
+model, as on every backend — and emits a JSON document with per-family
+timings plus two advisory booleans:
 
   * ``auto_no_slower_than_best_everywhere`` — auto within TOLERANCE of
     min(push, pull) on every family;
@@ -14,8 +13,10 @@ booleans:
 
 Times are best-of-``repeats`` wall clock of the jitted driver (compile
 excluded by a warmup run).  On CPU the engine uses the XLA reference
-sweeps; the relative ordering of the three forms is what is under test,
-not absolute throughput.
+sweeps, whose cost does not depend on occupancy, so auto's time here is
+what the model's choices cost on the CPU, not on the chip; the relative
+ordering of the three forms is what is reported, not absolute
+throughput.
 
 Two kernel-path comparisons ride along per family (interpret-mode
 Pallas): ``t_kernel_fused*`` vs ``t_kernel_push*`` time the fused

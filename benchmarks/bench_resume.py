@@ -73,9 +73,8 @@ def run(quick: bool = False, n_sources: int = 32, repeats: int = 3,
     from repro.core.options import SweepOptions
 
     chunk_size = 8
-    # pinned form: auto's wall-clock calibration makes direction_counts
-    # non-reproducible across invocations, and the in-bench full-vs-resume
-    # assertion covers them; dist/sigma/sweeps are form-invariant
+    # pinned form: the recorded baseline was taken in it; dist, sigma
+    # and sweeps are form-invariant
     opts = SweepOptions(source_batch=chunk_size, mode="sparse")
     names = QUICK_FAMILIES if quick else tuple(_families())
     families = {}

@@ -3,9 +3,9 @@ fixed-sparse vs auto, plus the scipy-Dijkstra external baseline.
 
 Mirror of ``bench_apsp``: one source tile through the
 ``core/weighted.py::weighted_apsp`` driver on each family with the form
-pinned to dense, pinned to sparse, and chosen by the engine (calibrated
-per graph on the CPU reference path), emitting a JSON document with
-per-family timings and the acceptance booleans:
+pinned to dense, pinned to sparse, and chosen per sweep by the engine's
+occupancy cost model, emitting a JSON document with per-family timings
+and two advisory booleans:
 
   * ``auto_no_slower_than_best_everywhere`` — auto within TOLERANCE of
     min(dense, sparse) on every family;

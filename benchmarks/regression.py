@@ -71,8 +71,8 @@ _HARD_FAMILY_FIELDS = ("n_nodes", "n_edges", "n_sources", "sweeps",
                        # autotuner: the static roofline plan is a pure
                        # function of graph shape + backend profile, so
                        # its checksum changing means the tuner decided
-                       # differently (tiles / fused gate / direction
-                       # pins), never that the machine was slow
+                       # differently (tiles / fused gate / cost
+                       # constants), never that the machine was slow
                        "tuning_plan_checksum")
 _BENCHES = ("bench_apsp", "bench_weighted", "bench_sharded",
             "bench_centrality", "bench_batching", "bench_serving",

@@ -253,7 +253,7 @@ class DawnGraph:
              profile=None) -> TuningPlan:
         """Build a roofline :class:`TuningPlan` for this graph, cache it
         on the handle (every later query consults it — tile sizes, the
-        fused gate, and deterministic ``mode="auto"`` direction pins),
+        fused gate, and the constants of ``mode="auto"``'s cost model),
         and optionally ``save`` it for reproducible reruns
         (``prepare(g, tuning="plan.json")``)."""
         plan = build_plan(self.prepared(), weights=self._lane_weights(),
@@ -286,8 +286,8 @@ def prepare(graph: Union[CSRGraph, DynamicCSRGraph], *, weights=None,
     ``weights=`` attaches static edge weights for the tropical semiring
     (a weighted :class:`DynamicCSRGraph` carries its own).
     ``tuning=`` accepts a :class:`TuningPlan` or the path of a saved one
-    (loaded with the backend-fingerprint check) — the reproducibility
-    lock for ``mode="auto"`` runs; build one with :meth:`DawnGraph.tune`.
+    (loaded with the backend-fingerprint check), so that a rerun uses the
+    same tiles and cost constants; build one with :meth:`DawnGraph.tune`.
     """
     if options is not None and opts:
         raise ValueError("pass options= or plain keywords, not both")

@@ -5,8 +5,7 @@ from .sweep import (Semiring, BOOLEAN, TROPICAL, MIN_LABEL, COUNTING,
                     SEMIRINGS, SweepState, make_state, sweep_loop,
                     boolean_forms, tropical_forms, minlabel_form,
                     counting_forms, minplus_candidates,
-                    derive_parents, time_sweep_forms, PUSH, PULL, SPARSE,
-                    DIRECTION_NAMES)
+                    derive_parents, PUSH, PULL, SPARSE, DIRECTION_NAMES)
 from .bovm import bovm_sweep, bovm_msbfs, bovm_sssp, DawnState
 from .sovm import sovm_sweep, sovm_sssp, sovm_msbfs, SovmState, reconstruct_path
 from .bfs import bfs_queue_numpy, bfs_scipy, bfs_level_sync_jax
@@ -19,17 +18,15 @@ from .weighted import (minplus_sssp, bucketed_sssp, expand_integer_weights,
                        dijkstra_oracle, WeightedResult, weighted_apsp,
                        WeightedApspResult, WeightedConfig,
                        PreparedWeightedGraph, prepare_weighted,
-                       measure_weighted_costs, WEIGHTED_FORM_NAMES)
+                       WEIGHTED_FORM_NAMES)
 from .centrality import (CentralityConfig, CentralityResult, CountingResult,
                          COUNTING_FORM_NAMES, MEASURES, betweenness,
                          brandes_dependencies, centrality, closeness,
                          counting_apsp, counting_apsp_blocks, eccentricity,
-                         eccentricity_sample, harmonic,
-                         measure_counting_costs)
+                         eccentricity_sample, harmonic)
 from .engine import (EngineConfig, SweepStats, ApspResult, PreparedGraph,
                      prepare_graph, frontier_stats, sweep_costs,
-                     choose_direction, measure_sweep_costs, apsp_engine,
-                     apsp_engine_blocks)
+                     choose_direction, apsp_engine, apsp_engine_blocks)
 from .jobs import (JobMismatchError, JobResult, WORKLOADS, run_sweep_job)
 from .autotune import (BackendProfile, GraphStats, TuningPlan,
                        backend_profile, build_plan, device_fingerprint,
@@ -41,7 +38,6 @@ __all__ = [
     "Semiring", "BOOLEAN", "TROPICAL", "MIN_LABEL", "COUNTING", "SEMIRINGS",
     "SweepState", "make_state", "sweep_loop", "boolean_forms",
     "tropical_forms", "minlabel_form", "counting_forms", "derive_parents",
-    "time_sweep_forms",
     "bovm_sweep", "bovm_msbfs", "bovm_sssp", "DawnState",
     "sovm_sweep", "sovm_sssp", "sovm_msbfs", "SovmState", "reconstruct_path",
     "bfs_queue_numpy", "bfs_scipy", "bfs_level_sync_jax",
@@ -53,16 +49,15 @@ __all__ = [
     "minplus_sssp", "bucketed_sssp", "expand_integer_weights",
     "dijkstra_oracle", "WeightedResult", "weighted_apsp",
     "WeightedApspResult", "WeightedConfig", "PreparedWeightedGraph",
-    "prepare_weighted", "measure_weighted_costs", "WEIGHTED_FORM_NAMES",
+    "prepare_weighted", "WEIGHTED_FORM_NAMES",
     "CentralityConfig", "CentralityResult", "CountingResult",
     "COUNTING_FORM_NAMES", "MEASURES", "betweenness", "brandes_dependencies",
     "centrality", "counting_apsp", "counting_apsp_blocks", "eccentricity",
-    "measure_counting_costs",
     "closeness", "harmonic", "eccentricity_sample",
     "PUSH", "PULL", "SPARSE", "DIRECTION_NAMES", "EngineConfig",
     "SweepStats", "ApspResult", "PreparedGraph", "prepare_graph",
     "frontier_stats", "sweep_costs", "choose_direction",
-    "measure_sweep_costs", "apsp_engine", "apsp_engine_blocks",
+    "apsp_engine", "apsp_engine_blocks",
     "JobMismatchError", "JobResult", "WORKLOADS", "run_sweep_job",
     "BackendProfile", "GraphStats", "TuningPlan", "backend_profile",
     "build_plan", "device_fingerprint", "tune_tiles",

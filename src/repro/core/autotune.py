@@ -2,14 +2,11 @@
 
 Two knobs dominate a sweep's cost and were, until this module, pinned by
 hand: the kernel tile shapes validated against the ~16 MB/core VMEM table
-in ``kernels/common.py``, and the per-sweep push/pull/sparse switch —
-dynamic occupancy cost model on the kernel path, *wall-clock calibration*
-(``sweep.time_sweep_forms``) on the reference path.  The calibration is
-the one non-deterministic decision in the engine: two identical
-``mode="auto"`` runs could race to different pinned directions and
-therefore different ``direction_counts``.
+in ``kernels/common.py``, and the constants of the per-sweep occupancy
+cost model (``engine.sweep_costs``) whose argmin picks the form at every
+sweep under ``mode="auto"``.
 
-:func:`build_plan` replaces both with a static roofline model:
+:func:`build_plan` sets both from a static roofline model:
 
   * a :class:`BackendProfile` supplies peak FLOP/s, HBM bandwidth and the
     per-core VMEM budget (``TPU_PROFILES``, keyed on the TPU's
@@ -28,11 +25,9 @@ therefore different ``direction_counts``.
 The result is a frozen, hashable, JSON-serializable :class:`TuningPlan`.
 Threading: ``SweepOptions.tuning`` carries the plan into every engine
 config; each engine calls :func:`apply` (tile/constant overlay, clamped
-to the current graph's padding) and consults
-:meth:`TuningPlan.pinned_direction` where it used to wall-clock-calibrate
-— ``mode="auto"`` becomes a pure function of (plan, graph shape, batch),
-so ``direction_counts`` are finally assertable under auto.  Precedence:
-an explicit ``mode=`` pin beats the plan, the plan beats calibration.
+to the current graph's padding).  The unit costs reach ``mode="auto"``
+only as the cost model's ``c_*`` constants: the plan never picks a form
+itself, and an explicit ``mode=`` pin ignores them.
 
 Import discipline: this module sits *below* the engines (imports
 options/sweep/kernels/launch only); ``engine``/``weighted``/
@@ -172,7 +167,7 @@ def graph_stats(g) -> GraphStats:
 
 def form_units(form: str, *, s: int, n_pad: int, m_pad: int) -> float:
     """Modelled work units of one sweep in ``form`` — the same counts the
-    engines' dynamic cost model uses (engine.sweep_costs), evaluated at
+    engines' per-sweep cost model uses (engine.sweep_costs), evaluated at
     full occupancy: dense GEMM elements for push/dense, uint32 words for
     pull, padded CSR lanes for sparse."""
     if form in ("push", "dense"):
@@ -195,9 +190,9 @@ class TuningPlan:
     inside the engines' jit-static configs.
 
     ``unit_costs`` is ``((semiring, form, seconds_per_unit), ...)``;
-    :meth:`pinned_direction` turns it into the deterministic replacement
-    for wall-clock calibration.  ``source`` records whether the costs
-    came from HLO analysis ("hlo") or the static fallback ("static").
+    :func:`apply` turns it into the cost model's ``c_*`` constants.
+    ``source`` records whether the costs came from HLO analysis ("hlo")
+    or the static fallback ("static").
     """
     backend: str                  # device fingerprint the plan is locked to
     vmem_budget: int              # bytes; budget the tiles were fit against
@@ -225,20 +220,6 @@ class TuningPlan:
         """True when every form the semiring dispatches has a cost."""
         return all(self.unit_cost(semiring, f) is not None
                    for f in FORM_VOCAB.get(semiring, ()))
-
-    def pinned_direction(self, semiring: str, *, s: int, n_pad: int,
-                         m_pad: int) -> Optional[int]:
-        """argmin form index for a whole batch — the deterministic
-        replacement for the calibrated (wall-clock) regime.  Index is in
-        the semiring engine's own direction order (FORM_VOCAB).  Returns
-        None when the plan lacks a cost for some form."""
-        vocab = FORM_VOCAB.get(semiring)
-        if not vocab or not self.covers(semiring):
-            return None
-        costs = [self.unit_cost(semiring, f)
-                 * form_units(f, s=s, n_pad=n_pad, m_pad=m_pad)
-                 for f in vocab]
-        return int(np.argmin(costs))
 
     # -- budget validation -------------------------------------------------
 
@@ -368,8 +349,8 @@ def _static_unit_costs(profile: BackendProfile
 
 
 def _representative_state(s: int, n_pad: int, dtype, unreached, visited_val):
-    """The same mid-sweep occupancy measure_sweep_costs uses: ~6%
-    frontier, ~25% visited."""
+    """A representative mid-sweep occupancy: ~6% frontier, ~25%
+    visited."""
     f = np.zeros((s, n_pad), np.int8)
     f[:, ::17] = 1
     dist = np.full((s, n_pad), unreached, dtype)
@@ -509,7 +490,7 @@ def apply(cfg: SweepOptions, *, semiring: str,
     """Overlay ``cfg.tuning`` onto an engine config: tile sizes (clamped
     back to MXU_ALIGN when they don't divide this graph's ``n_pad``),
     the fused-steps gate (only when the caller left ``fused_steps`` at
-    its 0 default — an explicit request wins), and the dynamic cost
+    its 0 default — an explicit request wins), and the per-sweep cost
     model's constants.  A config with no plan passes through unchanged.
     """
     plan = cfg.tuning

@@ -24,17 +24,17 @@ feeds everything here:
 The forward engine (:func:`counting_apsp`) mirrors ``weighted_apsp``:
 source tiles through the ONE sweep driver in ``core/sweep.py``, push
 (f32 counting GEMM — the Pallas kernel on the kernel path) vs sparse
-(scatter-add) chosen per sweep by the occupancy cost model or pinned by
-per-graph calibration.  Large jobs route through the sharded executor
-(``centrality(..., mesh=)``) — sources shard over the mesh's data axes,
-sigma partials combine with the masked-add ⊕-reduction in
-``core/distributed.py``.
+(scatter-add) chosen per sweep by the occupancy cost model under
+``mode="auto"``, or pinned by ``mode``.  Large jobs route through the
+sharded executor (``centrality(..., mesh=)``) — sources shard over the
+mesh's data axes, sigma partials combine with the masked-add
+⊕-reduction in ``core/distributed.py``.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import NamedTuple, Optional, Sequence, Tuple, Union
+from typing import NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -43,8 +43,7 @@ import numpy as np
 from ..graph.csr import CSRGraph
 from . import autotune
 from . import sweep as S
-from .engine import (PreparedGraph, _resolve_kernel, frontier_stats,
-                     prepare_graph)
+from .engine import PreparedGraph, frontier_stats, prepare_graph
 from .frontier import UNREACHED, one_hot_frontier
 from .options import SweepOptions
 from .sssp import multi_source
@@ -63,9 +62,8 @@ class CentralityConfig(SweepOptions):
     apply to f32 path counts).
 
     ``use_kernel=None`` resolves to "Pallas kernels iff on TPU" and
-    ``dynamic=None`` to "per-sweep switching iff on the kernel path",
-    exactly like the boolean/tropical engines; the calibrated regime
-    times the same counting closures the driver dispatches.
+    ``mode="auto"`` to per-sweep switching, exactly like the
+    boolean/tropical engines.
     """
     c_push: float = 1.0              # per f32 MAC in a live push tile
     c_sparse: float = 8.0            # per CSR gather + scatter-add lane
@@ -153,51 +151,6 @@ def _run_counting_batch(adj, src_idx, dst_idx, deg, sources, n_valid, *,
                         fused=fused, fused_steps=fused_steps)
 
 
-def measure_counting_costs(pg: PreparedGraph, s: int,
-                           cfg: CentralityConfig, *,
-                           use_kernel: bool = False,
-                           interpret: bool = True) -> Tuple[float, float]:
-    """Wall-clock one mid-run sweep of each counting form on this graph
-    (mirror of ``engine.measure_sweep_costs``; cached on the prepared
-    graph under a counting-tagged key)."""
-    key = ("counting", s, cfg.bn, cfg.bk, use_kernel, interpret)
-    if key in pg.cost_cache:
-        return pg.cost_cache[key]
-    n_pad = pg.n_pad
-    f = np.zeros((s, n_pad), np.int8)
-    f[:, ::17] = 1
-    dist = np.full((s, n_pad), int(UNREACHED), np.int32)
-    dist[:, ::4] = 1
-    sigma = (dist >= 0).astype(np.float32)
-    forms = S.counting_forms(pg.adj, pg.graph.src, pg.graph.dst,
-                             n_pad=n_pad, s=s, bn=cfg.bn, bk=cfg.bk,
-                             use_kernel=use_kernel, interpret=interpret)
-    result = S.time_sweep_forms(forms, jnp.asarray(f),
-                                (jnp.asarray(dist), jnp.asarray(sigma)))
-    pg.cost_cache[key] = result
-    return result
-
-
-def _resolve_counting_direction(pg: PreparedGraph, s: int,
-                                cfg: CentralityConfig, use_kernel: bool,
-                                interpret: bool) -> Optional[int]:
-    """None -> per-sweep dynamic switch; int -> form fixed per batch.
-    Pin precedence: explicit mode > TuningPlan argmin > wall-clock
-    calibration (see engine._resolve_direction)."""
-    if cfg.mode != "auto":
-        return COUNTING_FORM_NAMES.index(cfg.mode)
-    dynamic = use_kernel if cfg.dynamic is None else cfg.dynamic
-    if dynamic:
-        return None
-    if cfg.tuning is not None:
-        pinned = cfg.tuning.pinned_direction(
-            "counting", s=s, n_pad=pg.n_pad, m_pad=pg.graph.m_pad)
-        if pinned is not None:
-            return pinned
-    return int(np.argmin(measure_counting_costs(
-        pg, s, cfg, use_kernel=use_kernel, interpret=interpret)))
-
-
 def counting_apsp_blocks(g: Union[CSRGraph, PreparedGraph],
                          sources: Optional[Sequence[int]] = None, *,
                          config: CentralityConfig = CentralityConfig()):
@@ -215,11 +168,10 @@ def counting_apsp_blocks(g: Union[CSRGraph, PreparedGraph],
         raise ValueError(
             f"counting_apsp: sources must be in [0, {n}), got "
             f"[{srcs.min()}, {srcs.max()}]")
-    use_kernel, interpret = _resolve_kernel(config)
+    use_kernel, interpret = config.kernel_path()
     max_steps = config.max_steps or n
     B = config.source_batch
-    forced = _resolve_counting_direction(pg, B, config, use_kernel,
-                                         interpret)
+    forced = config.forced_form()
     fused_steps = 0
     if config.fused_steps and forced in (None, PUSH):
         fused_steps = S.resolve_fused_steps(

@@ -53,7 +53,7 @@ from ..graph.partition import edge_partition_global
 from ..kernels import registry as kernel_registry
 from . import autotune
 from . import sweep as S
-from .engine import _resolve_kernel, frontier_stats
+from .engine import frontier_stats
 from .frontier import (UNREACHED, one_hot_frontier, pack_bits,
                        unpack_bits)
 from .options import SweepOptions
@@ -501,8 +501,9 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
 
         forms = (dense_form or sparse_form, sparse_form or dense_form)
 
+        forced = cfg.forced_form()
         choose = None
-        if cfg.mode == "auto":
+        if forced is None:
             bs = min(s_l, 128)
 
             def choose(st: S.SweepState):
@@ -528,8 +529,7 @@ def _make_runner(mesh: Mesh, cfg: ShardedConfig, n_pad: int, n_real: int,
         state0 = (dist0_l, sigma0_l) if counting else dist0_l
         st = S.sweep_loop(forms, S.make_state(f0_l, state0, n_forms=2),
                           max_steps=steps, choose=choose, deg=deg_l,
-                          forced_dir=0 if cfg.mode in ("auto", "dense")
-                          else 1,
+                          forced_dir=0 if forced is None else forced,
                           converged=converged,
                           fused=fused, fused_steps=fused_steps_l,
                           fused_combine=fused_combine)
@@ -642,7 +642,7 @@ def sharded_apsp(g: Union[CSRGraph, ShardedOperands],
     padded = np.zeros(s_pad, np.int32)
     padded[: len(srcs)] = srcs
 
-    use_kernel, interpret = _resolve_kernel(cfg)
+    use_kernel, interpret = cfg.kernel_path()
     runner = _make_runner(ops.mesh, cfg, ops.n_pad, n, ops.m_local,
                           use_kernel, interpret, ops.n_shards)
     dist, sigma, step, dir_counts, edges = runner(

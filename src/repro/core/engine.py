@@ -18,26 +18,19 @@ profiles:
 This module tiles sources into MXU-aligned batches, runs each tile through
 the shared :func:`repro.core.sweep.sweep_loop` driver, and picks the
 cheapest form per sweep (direction-optimizing BFS in the style of Beamer's
-push/pull switch, generalized to three forms).  Two selection regimes:
-
-  dynamic (kernel path / TPU) — at every sweep, a ``lax.switch`` driven by
-    the occupancy cost model in :func:`sweep_costs`.  The signals are
-    exactly the scalar-prefetch tables the Pallas push kernel computes per
-    sweep, so the heuristic is free; tile skipping makes push cost truly
-    occupancy-proportional.
-
-  calibrated (reference path / CPU) — XLA's fixed-shape reference sweeps
-    cost the same regardless of occupancy, so per-sweep switching cannot
-    win.  Instead one sweep of each form is *measured* on the prepared
-    graph (sweep.time_sweep_forms) and the argmin direction is fixed for
-    the whole batch (zero per-sweep overhead; the measurement is cached
-    per graph).
+push/pull switch, generalized to three forms).  Under ``mode="auto"`` a
+``lax.switch`` at every sweep runs the form the occupancy cost model in
+:func:`sweep_costs` prices lowest, on every backend.  The signals are
+exactly the scalar-prefetch tables the Pallas push kernel computes per
+sweep, so the heuristic is free, and being a function of the state alone
+it makes ``direction_counts`` reproducible.  ``mode="<form>"`` pins one
+form for the whole batch (no stats, no switch).
 
 All three sweeps operate on identical padded state (frontier (S, n_pad)
 int8, dist (S, n_pad) int32), so switching costs nothing but the branch.
 The weighted analogue of this driver lives in core/weighted.py
-(``weighted_apsp``) and reuses the same cost model / calibration over the
-tropical forms.
+(``weighted_apsp``) and reuses the same cost model over the tropical
+forms.
 
 Thresholds and cost constants are documented in docs/ARCHITECTURE.md.
 """
@@ -65,7 +58,7 @@ class EngineConfig(SweepOptions):
     """Static boolean-engine parameters (a :class:`SweepOptions`
     subclass, hashable: used as a jit static arg).
 
-    Cost-model units (see docs/ARCHITECTURE.md for the calibration):
+    Cost-model units (see docs/ARCHITECTURE.md for their derivation):
       c_push   — per dense element in a live (i, j, k) push tile (MXU MAC)
       c_pull   — per uint32 word scanned by the pull sweep (VPU bitwise op;
                  one word covers 32 nodes, so the per-element cost is
@@ -110,8 +103,6 @@ class PreparedGraph:
     # content epoch of the source graph at prepare time (0 for a static
     # CSRGraph) — staleness checks in serve/ and repro.api key on it
     epoch: int = 0
-    # per-graph sweep-cost measurements, keyed (s, bn, bk, pull_chunk, path)
-    cost_cache: dict = dataclasses.field(default_factory=dict, repr=False)
     # landmark label tables for the distance-oracle serving tier
     # (serve/oracle.py builds them with apsp_engine — the batched engine
     # IS the preprocessing pass — and caches them here so every oracle
@@ -287,72 +278,8 @@ def _run_batch(adj, adj_pull, row_src, row_dst, deg, sources, n_valid, *,
 
 
 # --------------------------------------------------------------------------
-# calibrated direction choice (reference path)
-# --------------------------------------------------------------------------
-
-def measure_sweep_costs(pg: "PreparedGraph", s: int, cfg: EngineConfig, *,
-                        use_kernel: bool = False,
-                        interpret: bool = True) -> Tuple[float, float, float]:
-    """Wall-clock one mid-BFS sweep in each form on this graph.
-
-    Times the *same* sweep forms ``_run_batch`` will dispatch (kernel or
-    reference, per ``use_kernel``) via :func:`sweep.time_sweep_forms`, so
-    the pinned argmin is the argmin of what actually runs.  Reference
-    sweeps have occupancy-independent (fixed-shape) cost, so a single
-    measurement per form characterizes every sweep of the run.  Cached on
-    the PreparedGraph per (batch size, tiles, path) — calibration costs a
-    few warm sweeps once per graph, then is free.
-    """
-    key = (s, cfg.bn, cfg.bk, cfg.pull_chunk, use_kernel, interpret)
-    if key in pg.cost_cache:
-        return pg.cost_cache[key]
-    n_pad = pg.n_pad
-    # representative mid-BFS state: ~6% frontier, ~25% visited
-    f = np.zeros((s, n_pad), np.int8)
-    f[:, ::17] = 1
-    dist = np.full((s, n_pad), int(UNREACHED), np.int32)
-    dist[:, ::4] = 1
-    forms = S.boolean_forms(pg.adj, pg.adj_pull, pg.rows, n_pad=n_pad,
-                            s=s, bn=cfg.bn, bk=cfg.bk,
-                            pull_chunk=cfg.pull_chunk, use_kernel=use_kernel,
-                            interpret=interpret)
-    result = S.time_sweep_forms(forms, jnp.asarray(f), jnp.asarray(dist))
-    pg.cost_cache[key] = result
-    return result
-
-
-# --------------------------------------------------------------------------
 # public drivers
 # --------------------------------------------------------------------------
-
-def _resolve_kernel(cfg: EngineConfig) -> Tuple[bool, bool]:
-    on_tpu = jax.default_backend() == "tpu"
-    use_kernel = on_tpu if cfg.use_kernel is None else cfg.use_kernel
-    return use_kernel, not on_tpu
-
-
-def _resolve_direction(pg: "PreparedGraph", s: int, cfg: EngineConfig,
-                       use_kernel: bool, interpret: bool) -> Optional[int]:
-    """None -> per-sweep dynamic switch; int -> direction fixed per batch.
-
-    Precedence on the pinned path: an explicit ``mode=`` wins, then a
-    :class:`~repro.core.autotune.TuningPlan` (deterministic roofline
-    argmin), then wall-clock calibration (the legacy fallback — the only
-    non-deterministic regime, kept for plan-less runs)."""
-    if cfg.mode != "auto":
-        return DIRECTION_NAMES.index(cfg.mode)
-    dynamic = use_kernel if cfg.dynamic is None else cfg.dynamic
-    if dynamic:
-        return None
-    if cfg.tuning is not None:
-        pinned = cfg.tuning.pinned_direction(
-            "boolean", s=s, n_pad=pg.n_pad, m_pad=pg.graph.m_pad)
-        if pinned is not None:
-            return pinned
-    costs = measure_sweep_costs(pg, s, cfg, use_kernel=use_kernel,
-                                interpret=interpret)
-    return int(np.argmin(costs))
-
 
 def apsp_engine_blocks(
         g: Union[CSRGraph, PreparedGraph],
@@ -376,10 +303,10 @@ def apsp_engine_blocks(
             raise ValueError(
                 f"apsp_engine: sources must be in [0, {n}), got "
                 f"[{srcs.min()}, {srcs.max()}]")
-        use_kernel, interpret = _resolve_kernel(config)
+        use_kernel, interpret = config.kernel_path()
         max_steps = config.max_steps or n
         B = config.source_batch
-        forced_dir = _resolve_direction(pg, B, config, use_kernel, interpret)
+        forced_dir = config.forced_form()
         # fused multi-sweep blocks only exist on the kernel push path; the
         # resolver returns None (-> per-sweep loop) whenever the capability
         # is missing or the whole-operand residency would blow the VMEM

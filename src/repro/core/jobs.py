@@ -36,13 +36,6 @@ content hash, sources, workload, chunking) and resume refuses — with
 :class:`JobMismatchError` — to touch checkpoints written by a different
 job.
 
-One caveat: under ``mode="auto"`` on the reference (non-kernel) path
-the per-chunk direction choice is wall-clock calibrated, so
-``direction_counts`` — and only they — are not reproducible across
-invocations; ``dist`` / ``sigma`` / ``sweeps`` / ``edges_touched`` are
-form-invariant and stay bit-identical under any mode.  Pin a concrete
-``mode`` when the direction tallies themselves must survive a resume.
-
 Fault-injection seam: ``on_chunk(k)`` runs after chunk ``k``'s
 checkpoint is submitted; tests raise from it to simulate a kill between
 chunks (with ``checkpoint_interval > 1`` the newest chunks are then

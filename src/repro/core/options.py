@@ -9,10 +9,13 @@ dataclass; the caller-visible spread (``EngineConfig`` /
 most fields but nothing in the type system said so.
 
 :class:`SweepOptions` is the shared base: the fields every engine
-understands (source batching, form selection mode, kernel/dynamic
-resolution, sweep bound, fused blocks, kernel tiles).  The per-engine
-configs subclass it, adding only their engine-specific knobs (cost-model
-constants, extra tile sizes, the sharded semiring selector), so
+understands (source batching, form selection mode, kernel use, sweep
+bound, fused blocks, kernel tiles), and the one place where every
+engine resolves its form and its kernel use
+(:meth:`SweepOptions.forced_form`, :meth:`SweepOptions.kernel_path`).
+The per-engine configs subclass it, adding only their engine-specific
+knobs (cost-model constants, extra tile sizes, the sharded semiring
+selector), so
 
   * a plain ``SweepOptions`` can be projected onto any engine config via
     :meth:`SweepOptions.to` (the ``dawn`` facade in ``repro/api.py``
@@ -30,6 +33,8 @@ from __future__ import annotations
 import dataclasses
 from typing import TYPE_CHECKING, ClassVar, Optional, Tuple
 
+import jax
+
 if TYPE_CHECKING:  # import cycle: autotune builds ON options
     from .autotune import TuningPlan
 
@@ -42,14 +47,14 @@ class SweepOptions:
     jit static argument).
 
     ``mode`` names a sweep *form* ("push"/"pull"/"sparse" boolean,
-    "dense"/"sparse" tropical) or "auto" (cost-model selection).  The
-    base class accepts any string; each engine subclass pins the set it
+    "dense"/"sparse" tropical) or "auto": the per-sweep occupancy cost
+    model picks the form at every sweep, on every backend.  The base
+    class accepts any string; each engine subclass pins the set it
     dispatches via ``_mode_names`` and asserts membership.
     """
     source_batch: int = 128          # sources per tile (multiple of 8)
     mode: str = "auto"               # "auto" | an engine form name
     use_kernel: Optional[bool] = None  # None -> Pallas kernels iff on TPU
-    dynamic: Optional[bool] = None   # per-sweep switch; None -> use_kernel
     max_steps: Optional[int] = None  # None -> n_nodes (hop bound)
     # fused multi-sweep blocks: 0 = off, K > 0 = K sweeps per kernel
     # launch, -1 = whole fixpoint in one launch (kernel path only)
@@ -58,10 +63,9 @@ class SweepOptions:
     bn: int = 128
     bk: int = 128
     # optional roofline TuningPlan (core/autotune.py): every engine
-    # overlays it via autotune.apply (tiles, fused gate, cost constants)
-    # and, on the calibrated mode="auto" path, pins the direction from
-    # plan.pinned_direction instead of wall-clock timing — the
-    # determinism lock.  Frozen/hashable, so it rides the jit static arg.
+    # overlays it via autotune.apply (tiles, fused gate, and the cost
+    # model's constants, which is all mode="auto" reads of it).
+    # Frozen/hashable, so it rides the jit static arg.
     tuning: Optional["TuningPlan"] = None
 
     # subclasses pin the form names they dispatch; () = accept anything
@@ -79,6 +83,19 @@ class SweepOptions:
         assert self.fused_steps >= -1, \
             f"fused_steps must be -1 (whole fixpoint), 0 (off) or a " \
             f"positive sweep count, got {self.fused_steps}"
+
+    def forced_form(self) -> Optional[int]:
+        """The form index ``mode`` pins, in ``_mode_names`` order, or
+        None for "auto" (the per-sweep cost model chooses)."""
+        return None if self.mode == "auto" else \
+            self._mode_names.index(self.mode)
+
+    def kernel_path(self) -> Tuple[bool, bool]:
+        """``(use_kernel, interpret)``: Pallas kernels iff on TPU unless
+        ``use_kernel`` says otherwise; off the TPU they interpret."""
+        on_tpu = jax.default_backend() == "tpu"
+        use_kernel = on_tpu if self.use_kernel is None else self.use_kernel
+        return use_kernel, not on_tpu
 
     def to(self, cls, lenient: bool = False, **extra):
         """Project these options onto engine config class ``cls``.

@@ -108,8 +108,8 @@ def apsp(g: Union[CSRGraph, PreparedGraph], *, block: int = 128,
     """All-pairs via blocked multi-source sweeps.  Yields (sources, dist)
     blocks to avoid materializing the full (n, n) matrix for large n.
 
-    method='auto' prepares the graph once so engine operands and the
-    calibration cache are shared across every block."""
+    method='auto' prepares the graph once so engine operands are shared
+    across every block."""
     if method == "auto" and not isinstance(g, PreparedGraph):
         g = prepare_graph(g)
     n = (g.graph if isinstance(g, PreparedGraph) else g).n_nodes

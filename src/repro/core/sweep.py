@@ -25,9 +25,7 @@ non-idempotent ⊕ in the set).  This module owns:
     instantiates it with a semiring's forms instead of carrying its own
     loop;
   * :func:`derive_parents` — shortest-path-tree post-pass shared by the
-    batched paths that do not track parents in-loop;
-  * :func:`time_sweep_forms` — the wall-clock calibration primitive behind
-    the CPU-path direction choice (see core/engine.py).
+    batched paths that do not track parents in-loop.
 
 A *form* is a callable ``(frontier, dist, parent, step) -> (new_frontier,
 dist, parent)``.  ``new_frontier`` is the set of entries improved by the
@@ -42,7 +40,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import time
 from typing import Any, Callable, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -452,8 +449,8 @@ def boolean_forms(adj, adj_pull, rows, *, n_pad: int, s: int,
                   accum_dtype=jnp.float32) -> Tuple[SweepForm, ...]:
     """(push, pull, sparse) boolean sweep forms over identical state —
     the single source of truth for what each direction dispatches, shared
-    by the batch driver, the single-source paths, and the calibration
-    measurement.
+    by the batch driver, the single-source paths, and the autotuner's
+    HLO pricing.
 
     ``rows`` is the sparse form's operand, the graph's :func:`dst_rows`
     layout ``(row_src, row_dst)``: the form gathers the frontier at
@@ -768,52 +765,3 @@ def derive_parents(g, dist: jax.Array, *, weights=None) -> jax.Array:
     cand = jnp.where(ok, g.src, -1)
     par = jnp.full(d.shape, -1, jnp.int32).at[..., g.dst].max(cand)
     return par[..., :n]
-
-
-# --------------------------------------------------------------------------
-# wall-clock form calibration (the CPU-path direction signal)
-# --------------------------------------------------------------------------
-
-_CALIBRATION_SWEEPS = 8
-_CALIBRATION_REPS = 5
-
-
-def time_sweep_forms(forms: Sequence[SweepForm], frontier, dist,
-                     parent: Optional[jax.Array] = None, *,
-                     n_sweeps: int = _CALIBRATION_SWEEPS,
-                     reps: int = _CALIBRATION_REPS) -> Tuple[float, ...]:
-    """Median wall-clock seconds per sweep for each form on the given
-    mid-BFS state.  Times a jitted block of ``n_sweeps`` chained sweeps so
-    per-dispatch timer noise is drowned; the frontier must evolve or XLA
-    hoists the loop-invariant sweep out of the fori_loop, so ``dist`` is
-    refreshed every other sweep to keep the frontier alive.  Fixed-shape
-    XLA sweeps cost the same at any occupancy, so one measurement
-    characterizes every sweep of a run (see core/engine.py calibration).
-    """
-    if parent is None:
-        parent = jnp.zeros((1,), jnp.int32)
-
-    def chained(form):
-        def go(fr, d, p):
-            def body(i, c):
-                new, dd, pp = form(c[0], c[1], c[2], i + 1)
-                # dist may be a pytree (the counting semiring carries a
-                # (dist, sigma) pair) — refresh every leaf
-                refreshed = jax.tree_util.tree_map(
-                    lambda orig, upd: jnp.where(i % 2 == 1, orig, upd),
-                    d, dd)
-                return (new, refreshed, pp)
-            return jax.lax.fori_loop(0, n_sweeps, body, (fr, d, p))
-        return jax.jit(go)
-
-    costs = []
-    for form in forms:
-        fn = chained(form)
-        jax.block_until_ready(fn(frontier, dist, parent))  # compile + warm
-        samples = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(frontier, dist, parent))
-            samples.append(time.perf_counter() - t0)
-        costs.append(sorted(samples)[reps // 2] / n_sweeps)
-    return tuple(costs)

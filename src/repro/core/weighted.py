@@ -17,9 +17,9 @@ picks between the tropical forms —
            O(S · m_pad) regardless of occupancy); kernel path: the
            Pallas edge-block relax
 
-— chosen per sweep by the occupancy cost model (dynamic regime) or pinned
-per graph by wall-clock calibration of both forms (CPU regime), exactly
-mirroring core/engine.py.  Public entry points:
+— chosen per sweep by the occupancy cost model under ``mode="auto"``, or
+pinned by ``mode``, exactly mirroring core/engine.py.  Public entry
+points:
 
   * ``minplus_sssp``   — single-source (min,+) sweeps through the shared
                          driver (frontier-gated Bellman-Ford; sweep count
@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import NamedTuple, Optional, Sequence, Tuple, Union
+from typing import NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -46,7 +46,7 @@ import numpy as np
 from ..graph.csr import CSRGraph
 from . import autotune
 from . import sweep as S
-from .engine import _resolve_kernel, frontier_stats
+from .engine import frontier_stats
 from .frontier import one_hot_frontier
 from .options import SweepOptions
 from .sovm import sovm_sssp
@@ -81,9 +81,8 @@ class WeightedConfig(SweepOptions):
 
     ``use_kernel=None`` resolves to "Pallas kernels iff on TPU", exactly
     like ``EngineConfig``; the kernel closures come from the semiring
-    kernel registry via ``sweep.tropical_forms``.  ``dynamic=None``
-    mirrors the boolean engine too: per-sweep occupancy switching on the
-    kernel path, per-graph wall-clock calibration on the reference path.
+    kernel registry via ``sweep.tropical_forms``.  ``mode="auto"``
+    mirrors the boolean engine too: per-sweep occupancy switching.
 
     ``max_sweeps`` is this engine's historical spelling of the base
     ``max_steps`` hop bound; setting either sets both.
@@ -115,7 +114,6 @@ class PreparedWeightedGraph:
     n_pad: int
     # content epoch of the source graph at prepare time (0 = static)
     epoch: int = 0
-    cost_cache: dict = dataclasses.field(default_factory=dict, repr=False)
     _wdense: Optional[jax.Array] = dataclasses.field(default=None,
                                                      repr=False)
 
@@ -235,51 +233,6 @@ def _run_weighted_batch(wdense, src_idx, dst_idx, w_edges, deg, sources,
                         fused=fused, fused_steps=fused_steps)
 
 
-def measure_weighted_costs(pw: PreparedWeightedGraph, s: int,
-                           cfg: WeightedConfig, *,
-                           use_kernel: bool = False,
-                           interpret: bool = True) -> Tuple[float, float]:
-    """Wall-clock one mid-run sweep of each tropical form on this graph
-    (mirror of engine.measure_sweep_costs; cached on the prepared graph).
-    Times the same closures ``_run_weighted_batch`` will dispatch (kernel
-    or reference, per ``use_kernel``)."""
-    key = (s, cfg.chunk, cfg.bn, cfg.bk, cfg.eb, use_kernel, interpret)
-    if key in pw.cost_cache:
-        return pw.cost_cache[key]
-    n_pad = pw.n_pad
-    f = np.zeros((s, n_pad), np.int8)
-    f[:, ::17] = 1
-    dist = np.full((s, n_pad), np.inf, np.float32)
-    dist[:, ::4] = 1.0
-    forms = S.tropical_forms(pw.wdense, pw.graph.src, pw.graph.dst,
-                             pw.w_edges, n_pad=n_pad, chunk=cfg.chunk,
-                             use_kernel=use_kernel, interpret=interpret,
-                             bn=cfg.bn, bk=cfg.bk, eb=cfg.eb)
-    result = S.time_sweep_forms(forms, jnp.asarray(f), jnp.asarray(dist))
-    pw.cost_cache[key] = result
-    return result
-
-
-def _resolve_weighted_direction(pw: PreparedWeightedGraph, s: int,
-                                cfg: WeightedConfig, use_kernel: bool,
-                                interpret: bool) -> Optional[int]:
-    """None -> per-sweep dynamic switch; int -> form fixed per batch.
-    Pin precedence: explicit mode > TuningPlan argmin > wall-clock
-    calibration (see engine._resolve_direction)."""
-    if cfg.mode != "auto":
-        return WEIGHTED_FORM_NAMES.index(cfg.mode)
-    dynamic = use_kernel if cfg.dynamic is None else cfg.dynamic
-    if dynamic:
-        return None
-    if cfg.tuning is not None:
-        pinned = cfg.tuning.pinned_direction(
-            "tropical", s=s, n_pad=pw.n_pad, m_pad=pw.graph.m_pad)
-        if pinned is not None:
-            return pinned
-    return int(np.argmin(measure_weighted_costs(
-        pw, s, cfg, use_kernel=use_kernel, interpret=interpret)))
-
-
 def weighted_apsp(g: Union[CSRGraph, PreparedWeightedGraph],
                   weights=None,
                   sources: Optional[Sequence[int]] = None, *,
@@ -288,8 +241,8 @@ def weighted_apsp(g: Union[CSRGraph, PreparedWeightedGraph],
     """Batched multi-source tropical APSP with direction optimization.
 
     Pass a :class:`PreparedWeightedGraph` (weights=None) to reuse
-    operands and the calibration cache across calls (the serving path
-    does).  Distances are float32 with +inf for unreachable targets.
+    operands across calls (the serving path does).  Distances are
+    float32 with +inf for unreachable targets.
     """
     pw = g if isinstance(g, PreparedWeightedGraph) else \
         prepare_weighted(g, weights)
@@ -306,11 +259,8 @@ def weighted_apsp(g: Union[CSRGraph, PreparedWeightedGraph],
             f"[{srcs.min()}, {srcs.max()}]")
     max_sweeps = config.max_sweeps or n
     B = config.source_batch
-    # one resolution policy for both semirings: _resolve_kernel only
-    # reads cfg.use_kernel, which WeightedConfig shares with EngineConfig
-    use_kernel, interpret = _resolve_kernel(config)
-    forced = _resolve_weighted_direction(pw, B, config, use_kernel,
-                                         interpret)
+    use_kernel, interpret = config.kernel_path()
+    forced = config.forced_form()
     fused_steps = 0
     if config.fused_steps and forced in (None, DENSE):
         fused_steps = S.resolve_fused_steps(

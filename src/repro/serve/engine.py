@@ -642,7 +642,7 @@ class GraphService:
             id(q): {} for q in queries}
         # one batched run over only the queries that need per-source
         # measures (betweenness-only queries are served from the cache),
-        # reusing the service's prepared operands and calibration cache
+        # reusing the service's prepared operands
         ps_queries = [q for q in queries
                       if set(q.analytics) - {"betweenness"}]
         if ps_queries:

@@ -7,9 +7,10 @@ Three claims under test (ISSUE 10 acceptance criteria):
   1. Autotuned configs are bit-identical (dist/parent/sigma) to default
      configs on every adversarial family × boolean/tropical/counting ×
      ref/kernel path.
-  2. A pinned TuningPlan makes two ``mode="auto"`` runs agree on
-     ``direction_counts`` — the plan's analytic argmin replaces the
-     wall-clock calibration race (the PR 9 non-determinism).
+  2. A TuningPlan reaches ``mode="auto"`` only through
+     ``autotune.apply``'s overlay (tiles and the cost model's ``c_*``
+     constants): two plan-carrying runs agree on ``direction_counts``,
+     and so does a plan-less run given that overlay.
   3. ``save`` → ``load`` round-trips exactly, refuses a foreign backend
      fingerprint, and every emitted tile shape fits the
      push/pull/fused VMEM budgets of every registered KernelSet.
@@ -24,8 +25,7 @@ import pytest
 from repro.graph.csr import CSRGraph
 from repro.core import autotune
 from repro.core.autotune import (FORM_VOCAB, TuningPlan, backend_profile,
-                                 build_plan, form_units, graph_stats,
-                                 tune_tiles)
+                                 build_plan, tune_tiles)
 from repro.core.engine import EngineConfig, apsp_engine, prepare_graph
 from repro.core.weighted import WeightedConfig, weighted_apsp
 from repro.core.centrality import CentralityConfig, counting_apsp
@@ -123,13 +123,14 @@ def test_autotuned_bit_identical_counting(family, use_kernel, plan_cache):
 
 
 # --------------------------------------------------------------------------
-# the determinism lock (the PR 9 mode="auto" regression)
+# how a plan reaches mode="auto": the cost model's constants
 # --------------------------------------------------------------------------
 
 def test_auto_direction_counts_deterministic_with_plan():
-    """Two identical mode="auto" runs with the same pinned plan must
-    report identical direction_counts — and the pinned direction is
-    exactly the plan's analytic argmin, not a timing race."""
+    """Two identical mode="auto" runs with the same plan report identical
+    direction_counts, and a run without the plan whose tiles and c_*
+    constants are autotune.apply's overlay matches them count for count:
+    the overlay is the only way the plan reaches the per-sweep choice."""
     g = _graph("random_ragged")
     plan = build_plan(g, use_hlo=False)
     cfg = EngineConfig(source_batch=16, mode="auto", use_kernel=False,
@@ -138,13 +139,15 @@ def test_auto_direction_counts_deterministic_with_plan():
     r2 = apsp_engine(g, config=cfg)
     np.testing.assert_array_equal(np.asarray(r1.direction_counts),
                                   np.asarray(r2.direction_counts))
-    pg = prepare_graph(g)
-    want = plan.pinned_direction("boolean", s=16, n_pad=pg.n_pad,
-                                 m_pad=g.m_pad)
-    counts = np.asarray(r1.direction_counts)
-    assert counts.sum() > 0
-    # every sweep ran in the plan-pinned form
-    assert counts[want] == counts.sum(), (counts, want)
+    # the overlay (tiles and c_* constants) with the plan itself dropped
+    plain = dataclasses.replace(
+        autotune.apply(cfg, semiring="boolean",
+                       n_pad=prepare_graph(g).n_pad), tuning=None)
+    r3 = apsp_engine(g, config=plain)
+    np.testing.assert_array_equal(np.asarray(r1.direction_counts),
+                                  np.asarray(r3.direction_counts))
+    np.testing.assert_array_equal(np.asarray(r1.dist), np.asarray(r3.dist))
+    assert np.asarray(r1.direction_counts).sum() > 0
 
 
 def test_auto_deterministic_through_jobs_layer():
@@ -163,20 +166,6 @@ def test_auto_deterministic_through_jobs_layer():
     np.testing.assert_array_equal(np.asarray(j1.dist), np.asarray(j2.dist))
     np.testing.assert_array_equal(np.asarray(j1.direction_counts),
                                   np.asarray(j2.direction_counts))
-
-
-@pytest.mark.parametrize("semiring", sorted(FORM_VOCAB))
-def test_pinned_direction_is_analytic_argmin(semiring):
-    plan = build_plan(_graph("path"), use_hlo=False)
-    stats = graph_stats(_graph("path"))
-    idx = plan.pinned_direction(semiring, s=8, n_pad=stats.n_pad,
-                                m_pad=stats.m_pad)
-    vocab = FORM_VOCAB[semiring]
-    costs = [plan.unit_cost(semiring, f)
-             * form_units(f, s=8, n_pad=stats.n_pad, m_pad=stats.m_pad)
-             for f in vocab]
-    assert idx == int(np.argmin(costs))
-    assert 0 <= idx < len(vocab)
 
 
 def test_hlo_plan_build_is_deterministic():

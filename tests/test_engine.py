@@ -4,13 +4,16 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.core import (EngineConfig, apsp_engine, bfs_queue_numpy,
-                        choose_direction, frontier_stats,
-                        measure_sweep_costs, prepare_graph, sweep_costs,
-                        PUSH, PULL, SPARSE, UNREACHED)
+from repro.core import (CentralityConfig, EngineConfig, WeightedConfig,
+                        apsp_engine, bfs_queue_numpy, choose_direction,
+                        counting_apsp, frontier_stats, prepare_graph,
+                        sweep_costs, weighted_apsp, PUSH, PULL, SPARSE,
+                        UNREACHED)
 from repro.core import sweep as S
 from repro.graph import generators as gen
 from repro.graph.csr import CSRGraph
+
+from oracles import bfs_dists, bfs_sigmas, dijkstra_dists
 
 
 def _ref_dists(g, sources):
@@ -57,14 +60,56 @@ def test_fixed_modes_agree(mode):
 
 
 def test_dynamic_per_sweep_switching_is_exact():
-    """The lax.switch path (per-sweep heuristic, kernel regime) must give
-    identical distances to the calibrated-static path."""
+    """The lax.switch path (the per-sweep heuristic mode="auto" runs)
+    must give exact distances."""
     g = gen.watts_strogatz(140, 6, 0.08, seed=5)
     sources = np.arange(16, dtype=np.int32)
-    dyn = apsp_engine(g, sources, config=EngineConfig(source_batch=16,
-                                                      dynamic=True))
+    dyn = apsp_engine(g, sources, config=EngineConfig(source_batch=16))
     np.testing.assert_array_equal(np.asarray(dyn.dist),
                                   _ref_dists(g, sources))
+
+
+def _auto_runs(semiring, g, sources):
+    """(run, expected) for one shell under mode="auto" on the reference
+    path with no plan: ``run()`` -> (arrays to compare, direction_counts)."""
+    if semiring == "boolean":
+        cfg = EngineConfig(source_batch=16, use_kernel=False)
+
+        def run():
+            r = apsp_engine(g, sources, config=cfg)
+            return (r.dist,), r.direction_counts
+        return run, (bfs_dists(g, sources),)
+    if semiring == "tropical":
+        gs, gd = g.edge_arrays_np()
+        w = ((gs * 7 + gd * 3) % 9 + 1).astype(np.float32)
+        cfg = WeightedConfig(source_batch=16, use_kernel=False)
+
+        def run():
+            r = weighted_apsp(g, w, sources, config=cfg)
+            return (r.dist,), r.direction_counts
+        return run, (dijkstra_dists(g, w, sources),)
+    cfg = CentralityConfig(source_batch=16, use_kernel=False)
+
+    def run():
+        r = counting_apsp(g, sources, config=cfg)
+        return (r.dist, r.sigma), r.direction_counts
+    return run, (bfs_dists(g, sources), bfs_sigmas(g, sources))
+
+
+@pytest.mark.parametrize("semiring", ["boolean", "tropical", "counting"])
+def test_auto_reference_path_is_exact_and_reproducible(semiring):
+    """mode="auto" with no plan on the reference path: the per-sweep cost
+    model chooses every form, the results equal the plain references, and
+    two runs report the same direction_counts."""
+    g = gen.erdos_renyi(160, 3.0, seed=11)
+    sources = np.arange(16, dtype=np.int32)
+    run, want = _auto_runs(semiring, g, sources)
+    got, counts = run()
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), b, rtol=1e-6)
+    _, again = run()
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(again))
+    assert int(np.asarray(counts).sum()) > 0
 
 
 def test_kernel_path_matches_ref():
@@ -135,15 +180,6 @@ def test_heuristic_sparse_on_sparse_graph():
                                    cfg=cfg))
     assert costs.shape == (3,)
     assert int(np.argmin(costs)) == SPARSE
-
-
-def test_calibration_measures_and_caches():
-    g = gen.erdos_renyi(150, 4.0, directed=False, seed=2)
-    pg = prepare_graph(g)
-    cfg = EngineConfig(source_batch=16)
-    costs = measure_sweep_costs(pg, 16, cfg)
-    assert len(costs) == 3 and all(c > 0 for c in costs)
-    assert measure_sweep_costs(pg, 16, cfg) is costs  # cached
 
 
 # -- graph stats feeding the engine -----------------------------------------
@@ -239,7 +275,7 @@ def test_auto_on_rows_matches_lanes(monkeypatch):
     8 against rows of one lane each (the lane form in CSC order)."""
     g = _kronecker()
     sources = np.arange(40, dtype=np.int32)
-    cfg = EngineConfig(source_batch=16, dynamic=True)
+    cfg = EngineConfig(source_batch=16)
     rows = apsp_engine(g, sources, config=cfg)
     monkeypatch.setattr(S, "dst_rows", lambda *a, n_real: S._dst_rows(
         *a, n_real=n_real, width=1))

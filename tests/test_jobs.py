@@ -22,7 +22,6 @@ from oracles import adversarial_families, bfs_dists
 
 import repro as dawn
 from repro.core import SweepOptions
-from repro.core.autotune import build_plan
 from repro.core.engine import EngineConfig, apsp_engine
 from repro.core.centrality import CentralityConfig, counting_apsp
 from repro.core.jobs import JobMismatchError, JobResult, run_sweep_job
@@ -48,15 +47,7 @@ def _graphs():
             if name in keep}
 
 
-# mode="auto" used to need a pinned form here: the reference (CPU) path
-# picked the direction by wall-clock calibration, so direction_counts
-# were not reproducible across invocations.  A TuningPlan replaces the
-# calibration with an analytic roofline argmin (core/autotune.py), which
-# makes auto deterministic — the very property these resume tests
-# compare.  One static plan serves every family: the direction pin uses
-# per-call (s, n_pad, m_pad) and tiles clamp per graph.
-_PLAN = build_plan(_graphs()["random_ragged"], use_hlo=False)
-OPTS = SweepOptions(source_batch=8, mode="auto", tuning=_PLAN)
+OPTS = SweepOptions(source_batch=8, mode="auto")
 
 
 def _assert_results_equal(a: JobResult, b: JobResult):
